@@ -17,10 +17,13 @@ Four claims, all asserted:
    measured average gradient norm sits below the staleness-inflated
    Theorem-1 bound with constants estimated from the same run, and that
    bound sits above the synchronous one (the (I+s)² − I² drift term).
-4. **Sharded async round end-to-end** — a subprocess with
+4. **Sharded async round end-to-end, rehearsed on the CPU** — a
+   subprocess held to the CPU (``JAX_PLATFORMS=cpu``) with
    XLA_FLAGS=--xla_force_host_platform_device_count=4 drives
    ``launch.train --shard-data 4 --staleness 1`` through the shard_map
-   engine, the async queue drain, and the checkpoint save.
+   engine and the async queue drain.  The parent has touched JAX by then
+   and may hold the accelerator, so the child never asks for it; the
+   sharded round on real chips is ``chip_smoke.py --four-chips``.
 """
 from __future__ import annotations
 
@@ -56,6 +59,7 @@ def _collapse_rows(quick: bool, seed: int) -> list:
     from repro.core.convergence import synthetic_hyperspec, theorem1_bound
     from repro.core.tiers import default_plan
     from repro.data import image_loader, make_cifar10_like, partition_iid
+    from repro.launch.train import fed_round
     from repro.models.vgg import VggModel
     from repro.optim import sgd
 
@@ -82,8 +86,7 @@ def _collapse_rows(quick: bool, seed: int) -> list:
     cache, sync_losses = {}, []
     state = init_state_a(model, plan, opt, jax.random.PRNGKey(seed))
     for r, batch in enumerate(batches()):
-        fed = tuple((r + 1) % I == 0 if I > 1 else True
-                    for I in plan.intervals)
+        fed = fed_round(plan.intervals, r)
         if fed not in cache:
             cache[fed] = jax.jit(
                 build_train_step_a(model, plan, opt, fed_round=fed)
@@ -211,6 +214,7 @@ def _envelope_rows(quick: bool, seed: int) -> list:
 
 def _sharded_round_rows(quick: bool, seed: int) -> list:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = "src"
     cmd = [
@@ -224,7 +228,8 @@ def _sharded_round_rows(quick: bool, seed: int) -> list:
                          timeout=540)
     ok = out.returncode == 0 and "sharded over" in out.stdout
     assert ok, (out.stdout[-1500:], out.stderr[-1500:])
-    return [("sharded_round_subprocess", "smollm-135m x4dev", 2.0, 0.0, ok)]
+    return [("sharded_round_cpu_rehearsal", "smollm-135m x4 cpu devices",
+             2.0, 0.0, ok)]
 
 
 def main(quick: bool = False, seed: int = 0) -> list:

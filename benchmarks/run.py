@@ -41,7 +41,8 @@ Harness -> paper artifact map (details in DESIGN.md §7):
     async_scale           (ours)     sharded async engine (DESIGN.md §17):
                                      staleness-0 bit-exact collapse, 10^6-client
                                      async-vs-sync round pricing, staleness-
-                                     inflated Thm 1 envelope, sharded subprocess
+                                     inflated Thm 1 envelope, CPU rehearsal
+                                     of a sharded round
     roofline              §g         three-term roofline per (arch x shape)
 """
 from __future__ import annotations
@@ -155,7 +156,7 @@ def _registry(args):
         ("fault_tolerance", "training",
          lambda: fault_tolerance.main(args.quick, seed=args.seed)),
         # prices + runs the sharded async engine (real s=0/s=1 training,
-        # a 10^6-client overlap sweep, and a sharded subprocess round)
+        # a 10^6-client overlap sweep, and a CPU-held sharded subprocess round)
         ("async_scale", "training",
          lambda: async_scale.main(args.quick, seed=args.seed)),
         ("roofline", "extracted", lambda: _roofline(roofline)),

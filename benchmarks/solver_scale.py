@@ -32,7 +32,6 @@ from repro.api import (
     build, evaluate_schedule,
 )
 from repro.core import solve_bcd
-from repro.core.batched import _HAS_JAX
 
 from .common import emit, record
 
@@ -102,7 +101,7 @@ def _sweep_point(
                  speedup if speedup is not None else float("nan")))
     rows.append((part, U, M, K, "numpy_warm", t_warm, float("nan")))
 
-    if _HAS_JAX and not quick:
+    if not quick:
         t_jax, r_jax, _ = _timed_bcd(U, M, seed, "jax", scenario)
         assert r_jax == r_np, f"jax optimum drifted at U={U} M={M}"
         rows.append((part, U, M, K, "jax", t_jax, float("nan")))

@@ -290,6 +290,7 @@ def _train(built: BuiltExperiment, cuts, intervals) -> Dict[str, Any]:
     """
     import os
     import tempfile
+    import time
 
     import jax
     import jax.numpy as jnp
@@ -388,9 +389,10 @@ def _train(built: BuiltExperiment, cuts, intervals) -> Dict[str, Any]:
 
     n_faulty_total = 0
     faulty_rounds = 0
-    losses = []
+    losses, round_seconds = [], []
     for r in range(rc.rounds):
         batch = {k: jnp.asarray(v) for k, v in loader.next_round().items()}
+        t0 = time.perf_counter()
         mrow = None
         if masks is not None:
             mrow = np.asarray(masks[r % masks.shape[0]], dtype=bool)
@@ -432,6 +434,8 @@ def _train(built: BuiltExperiment, cuts, intervals) -> Dict[str, Any]:
                 state.opt_state,
                 state.step,
             )
+        jax.block_until_ready((state, loss))
+        round_seconds.append(time.perf_counter() - t0)
         losses.append(float(loss))
         if ckpt_path is not None and (r + 1) % fc.checkpoint_every == 0:
             save_checkpoint(
@@ -471,6 +475,7 @@ def _train(built: BuiltExperiment, cuts, intervals) -> Dict[str, Any]:
         "first_loss": losses[0] if losses else None,
         "final_loss": losses[-1] if losses else None,
         "losses": losses,
+        "round_seconds": round_seconds,
         "thm1_bound": float(bound),
         "async": bool(use_async),
         "staleness": [int(v) for v in s_eff],

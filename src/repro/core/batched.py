@@ -23,17 +23,20 @@ oracle:
   solvers (enforced in ``tests/test_batched.py``).
 
 Backends: ``numpy`` is the reference implementation; ``jax`` runs the
-same chain jitted under ``enable_x64`` (float64 elementwise IEEE ops
-match NumPy exactly); ``auto`` picks jax only when the lattice is big
+same chain jitted under ``jax.enable_x64`` on the host CPU (float64
+elementwise IEEE ops match NumPy exactly; see :func:`x64_scope`); ``auto`` picks jax only when the lattice is big
 enough to amortize the per-shape jit compile.  The scalar walk stays
 available as ``backend="scalar"`` in the solvers and is the test oracle.
 See DESIGN.md §11.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 from typing import TYPE_CHECKING, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..compress.base import CompressionSpec, act_ratio, model_ratio
@@ -42,15 +45,6 @@ from .latency import BITS, LayerProfile, SystemSpec
 if TYPE_CHECKING:  # pragma: no cover - type hints only
     from .problem import HsflProblem
 
-try:  # CPU jax is in the image; keep the solver core importable without it
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    _HAS_JAX = True
-except Exception:  # pragma: no cover - exercised only on jax-less installs
-    _HAS_JAX = False
-
 BACKENDS = ("numpy", "jax")
 
 # auto picks jax only when the [K, N] chain is big enough to amortize the
@@ -58,17 +52,25 @@ BACKENDS = ("numpy", "jax")
 AUTO_JAX_MIN_ELEMS = 1_000_000
 
 
+@contextlib.contextmanager
+def x64_scope():
+    """The float64 scope every ``jax``-backend chain runs in.
+
+    It is pinned to the host CPU: this is the host-side float64 solver, not
+    the accelerator program, and a TPU's emulated float64 does not round
+    as NumPy does (the bit-equality tests fail there).
+    """
+    with jax.enable_x64(True), jax.default_device(jax.devices("cpu")[0]):
+        yield
+
+
 def resolve_backend(backend: str, work_elems: Optional[int] = None) -> str:
     """Map ``auto`` to a concrete backend (``scalar`` is handled upstream
     by the solvers, before the batched core is involved)."""
     if backend == "auto":
-        if not _HAS_JAX:
-            return "numpy"
         if work_elems is not None and work_elems < AUTO_JAX_MIN_ELEMS:
             return "numpy"
         return "jax"
-    if backend == "jax" and not _HAS_JAX:
-        raise RuntimeError("jax backend requested but jax is not importable")
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown batched backend {backend!r}; use numpy|jax|auto "
@@ -262,7 +264,7 @@ def accumulate_chain(
     """``[K]`` max-over-clients of the chain sum Σ_s work/rate, accumulated
     in stage order (the bit-exactness-critical reduction)."""
     if backend == "jax":
-        with enable_x64():
+        with x64_scope():
             return np.asarray(
                 _chain_jit(jnp.asarray(works), jnp.asarray(np.stack(rates, axis=0)))
             )
@@ -272,20 +274,19 @@ def accumulate_chain(
     return t.max(axis=1)
 
 
-if _HAS_JAX:
+@jax.jit
+def _chain_jit(works, rates):  # works [K, S], rates [S, N]
+    t = jnp.zeros((works.shape[0], rates.shape[1]), dtype=works.dtype)
+    for s in range(rates.shape[0]):
+        t = t + works[:, s][:, None] / rates[s][None, :]
+    return jnp.max(t, axis=1)
 
-    @jax.jit
-    def _chain_jit(works, rates):  # works [K, S], rates [S, N]
-        t = jnp.zeros((works.shape[0], rates.shape[1]), dtype=works.dtype)
-        for s in range(rates.shape[0]):
-            t = t + works[:, s][:, None] / rates[s][None, :]
-        return jnp.max(t, axis=1)
 
-    @jax.jit
-    def _agg_jit(lam, up, down):  # lam [K], up/down [J]
-        return jnp.max(lam[:, None] / up[None, :], axis=1) + jnp.max(
-            lam[:, None] / down[None, :], axis=1
-        )
+@jax.jit
+def _agg_jit(lam, up, down):  # lam [K], up/down [J]
+    return jnp.max(lam[:, None] / up[None, :], axis=1) + jnp.max(
+        lam[:, None] / down[None, :], axis=1
+    )
 
 
 def nominal_split_table(
@@ -319,7 +320,7 @@ def nominal_agg_table(
             continue  # Eq. (15)/(16) indicator
         up, down = system.model_up[m], system.model_down[m]
         if backend == "jax":
-            with enable_x64():
+            with x64_scope():
                 agg[:, m] = np.asarray(
                     _agg_jit(
                         jnp.asarray(lam[:, m]), jnp.asarray(up), jnp.asarray(down)

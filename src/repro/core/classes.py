@@ -42,6 +42,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..compress.base import model_ratio
@@ -51,21 +53,13 @@ from .batched import (
     resolve_backend,
     split_work_tensor,
     tier_d_lattice,
+    x64_scope,
 )
 from .convergence import class_weighted_G2_sums
 from .latency import BITS, per_client_split_latency
 from .ma_solver import MaSolution, _candidate_intervals, _theta_candidates
 from .ms_solver import _INFEASIBLE_MSG, solve_ms
 from .problem import INFEASIBLE, HsflProblem
-
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    _HAS_JAX = True
-except Exception:  # pragma: no cover - jax-less installs
-    _HAS_JAX = False
 
 
 _LATENCY_MODEL_MSG = (
@@ -485,8 +479,8 @@ def chain_matrix(
     """``[K, N]`` per-client chain sums Σ_s work/rate in stage order — the
     pre-max form of ``batched.accumulate_chain`` (per-class maxima need
     the per-client column structure)."""
-    if backend == "jax" and _HAS_JAX:
-        with enable_x64():
+    if backend == "jax":
+        with x64_scope():
             return np.asarray(
                 _chain_matrix_jit(
                     jnp.asarray(works), jnp.asarray(np.stack(rates, axis=0))
@@ -498,14 +492,12 @@ def chain_matrix(
     return t
 
 
-if _HAS_JAX:
-
-    @jax.jit
-    def _chain_matrix_jit(works, rates):  # works [K, S], rates [S, N]
-        t = jnp.zeros((works.shape[0], rates.shape[1]), dtype=works.dtype)
-        for s in range(rates.shape[0]):
-            t = t + works[:, s][:, None] / rates[s][None, :]
-        return t
+@jax.jit
+def _chain_matrix_jit(works, rates):  # works [K, S], rates [S, N]
+    t = jnp.zeros((works.shape[0], rates.shape[1]), dtype=works.dtype)
+    for s in range(rates.shape[0]):
+        t = t + works[:, s][:, None] / rates[s][None, :]
+    return t
 
 
 def product_assignments(K: int, C: int) -> np.ndarray:

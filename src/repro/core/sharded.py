@@ -38,7 +38,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..optim import Optimizer
@@ -109,7 +108,11 @@ def _matmul_group_mean(
 
     def f(x, k):
         flat = x.reshape(n_local, -1).astype(jnp.float32)
-        partial_sums = jnp.einsum("ng,nd->gd", ww, flat)       # [G, D] matmul
+        # HIGHEST: a TPU's default f32 matmul rounds its inputs to bf16,
+        # which would quantize every parameter in the mean
+        partial_sums = jnp.einsum(
+            "ng,nd->gd", ww, flat, precision=lax.Precision.HIGHEST
+        )                                                      # [G, D] matmul
         tot = lax.psum(partial_sums, axis_names)
         mean = tot / jnp.maximum(cnt, 1.0)[:, None]
         mine = mean[gid].astype(x.dtype).reshape(x.shape)      # gather my group
@@ -392,12 +395,12 @@ def build_sharded_train_step_a(
             return fn
         state_specs = train_pspecs(state, ca, N)
         batch_specs = batch_pspecs(batch, ca)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             _shard_step,
             mesh=mesh,
             in_specs=(state_specs, batch_specs, P(ca_spec)),
             out_specs=(state_specs, P(), P(ca_spec)),
-            check_rep=False,
+            check_vma=False,
         )
         fn = _cache[key] = jax.jit(mapped)
         return fn

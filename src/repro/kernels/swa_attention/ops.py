@@ -4,9 +4,9 @@
 (GQA), handles layout (head-major for the kernel grid), sequence padding to
 the 128 tile, head-dim padding to the 128 lane, the 1/√hd scale fold, and
 wires the forward/backward kernels through ``jax.custom_vjp``. Set
-``use_pallas=False`` to run the pure-jnp oracle; ``interpret=True`` (the
-default here) executes the kernel body in Python on CPU — on real TPU pass
-``interpret=False``.
+``use_pallas=False`` to run the pure-jnp oracle. The kernels compile for the
+TPU by default; on a CPU pass ``interpret=True`` to run the kernel body
+through the Pallas interpreter.
 """
 from __future__ import annotations
 
@@ -83,7 +83,7 @@ def swa_attention(
     v: jax.Array,  # [B, S, K, hd]
     window: int = 0,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     if window > 0:
         assert window % T == 0, f"window must be a multiple of {T}"

@@ -8,7 +8,9 @@ O(W + T) instead of O(S) — the structural win that makes 512k-token decode
 feasible. Online softmax in f32 VMEM scratch; -1e30 masking (not -inf) so
 fully-masked tiles stay NaN-free.
 
-Forward emits the per-row logsumexp; the backward pass (dq via a q-parallel
+Forward emits the per-row logsumexp as a [B, H, S, 1] column (a [T] row
+block of [B, H, S] is not a legal TPU block: its second-minor dim would be
+1 of H); the backward pass (dq via a q-parallel
 grid, dk/dv via a kv-parallel grid with an extra GQA group axis) recomputes
 tile scores from it, the standard flash-bwd trade of FLOPs for HBM.
 """
@@ -23,10 +25,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
-
-# renamed upstream: jax >= 0.5 exposes ``CompilerParams``, 0.4.x the
-# ``TPUCompilerParams`` spelling of the same dataclass
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def _pos(i, T):
@@ -89,8 +87,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     def _done():
         l = l_scr[:, :1]
         o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(out_dtype)
-        lse = m_scr[:, 0] + jnp.log(jnp.maximum(l[:, 0], 1e-30))
-        lse_ref[0, 0] = lse
+        lse_ref[0, 0] = m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def _fwd(q, k, v, *, window, T, S_true, interpret):
@@ -129,18 +126,18 @@ def _fwd(q, k, v, *, window, T, S_true, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, T, hd), q_map),
-            pl.BlockSpec((1, 1, T), lambda b, h, i, s: (b, h, i)),
+            pl.BlockSpec((1, 1, T, 1), lambda b, h, i, s: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((B, H, S), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((T, 128), jnp.float32),
             pltpu.VMEM((T, 128), jnp.float32),
             pltpu.VMEM((T, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -187,7 +184,7 @@ def _full_fwd_wrapper(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _done():
         l = l_scr[:, :1]
         o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(out_dtype)
-        lse_ref[0, 0] = m_scr[:, 0] + jnp.log(jnp.maximum(l[:, 0], 1e-30))
+        lse_ref[0, 0] = m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30))
 
 
 # --------------------------------------------------------------------------- #
@@ -215,8 +212,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0][:, None]          # [T, 1]
-    delta = delta_ref[0, 0][:, None]      # [T, 1]
+    lse = lse_ref[0, 0]                   # [T, 1]
+    delta = delta_ref[0, 0]               # [T, 1]
     sc = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -260,8 +257,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0][:, None]
-    delta = delta_ref[0, 0][:, None]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
     sc = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                      # [Tq, Tk]
@@ -295,8 +292,8 @@ def _bwd(q, k, v, o, lse, do, *, window, T, S_true, interpret):
     span = nq if full else (window // T) + 1
 
     delta = jnp.sum(
-        o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
-    )  # [B, H, S]
+        o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1, keepdims=True
+    )  # [B, H, S, 1]
 
     def q_map(b, h, i, s):
         return (b, h, i, 0)
@@ -307,7 +304,7 @@ def _bwd(q, k, v, o, lse, do, *, window, T, S_true, interpret):
         return (b, h // G, jnp.maximum(i - (span - 1) + s, 0), 0)
 
     def lse_map(b, h, i, s):
-        return (b, h, i)
+        return (b, h, i, 0)
 
     dq = pl.pallas_call(
         functools.partial(
@@ -319,13 +316,13 @@ def _bwd(q, k, v, o, lse, do, *, window, T, S_true, interpret):
             pl.BlockSpec((1, 1, T, hd), kv_map),
             pl.BlockSpec((1, 1, T, hd), kv_map),
             pl.BlockSpec((1, 1, T, hd), q_map),
-            pl.BlockSpec((1, 1, T), lse_map),
-            pl.BlockSpec((1, 1, T), lse_map),
+            pl.BlockSpec((1, 1, T, 1), lse_map),
+            pl.BlockSpec((1, 1, T, 1), lse_map),
         ],
         out_specs=pl.BlockSpec((1, 1, T, hd), q_map),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((T, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -341,7 +338,7 @@ def _bwd(q, k, v, o, lse, do, *, window, T, S_true, interpret):
 
     def lse_of_kv_map(b, kh, jb, g, s):
         i = jnp.minimum(jb + s, nq - 1)
-        return (b, kh * G + g, i)
+        return (b, kh * G + g, i, 0)
 
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -354,8 +351,8 @@ def _bwd(q, k, v, o, lse, do, *, window, T, S_true, interpret):
             pl.BlockSpec((1, 1, T, hd), kv_self_map),
             pl.BlockSpec((1, 1, T, hd), kv_self_map),
             pl.BlockSpec((1, 1, T, hd), q_of_kv_map),
-            pl.BlockSpec((1, 1, T), lse_of_kv_map),
-            pl.BlockSpec((1, 1, T), lse_of_kv_map),
+            pl.BlockSpec((1, 1, T, 1), lse_of_kv_map),
+            pl.BlockSpec((1, 1, T, 1), lse_of_kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, T, hd), kv_self_map),
@@ -369,7 +366,7 @@ def _bwd(q, k, v, o, lse, do, *, window, T, S_true, interpret):
             pltpu.VMEM((T, hd), jnp.float32),
             pltpu.VMEM((T, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary", "arbitrary",
             ),

@@ -3,8 +3,9 @@
 ``aggregate_tree`` flattens a client-stacked pytree (leaves [N, ...]) into
 one [N, P] buffer view per leaf, runs the kernel, and reassembles —
 exactly what ``tiers.synchronize`` does per (tier, level), but in one fused
-HBM pass per leaf. On CPU (tests / this container) ``interpret=True`` runs
-the same kernel body in Python; on TPU set ``interpret=False``.
+HBM pass per leaf. The kernels compile for the TPU by default; on a CPU
+pass ``interpret=True`` to run the same kernel body through the Pallas
+interpreter.
 
 ``tiered_aggregate_q8`` is the compressed-wire entry (DESIGN.md §9): it
 takes the raw [N, P] shard, produces the int8-plus-per-tile-scale wire
@@ -20,8 +21,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ...compress.quantize import q8_dequantize, q8_quantize
-from .ref import ragged_quantized_tiered_aggregate_ref, tiered_aggregate_ref
+from ...compress.quantize import q8_quantize
+from .ref import q8_tile, q8_tiles_apply, ragged_q8_tile, tiered_aggregate_ref
 from .tiered_aggregate import (
     TILE_P,
     quantized_tiered_aggregate_pallas,
@@ -41,7 +42,7 @@ def tiered_aggregate(
     num_entities: int,
     tile_p: int = TILE_P,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """[N, P] fused two-level aggregation (see ref.py for semantics)."""
     do_entity = jnp.asarray(do_entity)
@@ -54,9 +55,47 @@ def tiered_aggregate(
     return tiered_aggregate_ref(x, weights, do_entity, do_global, num_entities)
 
 
-@partial(
-    jax.jit, static_argnames=("num_entities", "tile_p", "use_pallas", "interpret")
-)
+# The wire payload is a program of its own, as on a real link: the
+# aggregation reads the int8 payload from HBM.  Fused into one program with
+# the aggregation, XLA would round each branch's dequantize differently.
+_q8_wire = jax.jit(q8_quantize, static_argnames=("tile",))
+_AGG_STATIC = ("num_entities", "tile_p", "use_pallas", "interpret")
+
+
+@partial(jax.jit, static_argnames=_AGG_STATIC)
+def _aggregate_q8(
+    q, scales, weights, do_entity, do_global, *, num_entities, tile_p,
+    use_pallas, interpret,
+):
+    if use_pallas:
+        return quantized_tiered_aggregate_pallas(
+            q, scales, weights, do_entity, do_global, num_entities,
+            tile_p=tile_p, interpret=interpret,
+        )
+    return q8_tiles_apply(
+        q8_tile, q, scales, tile_p, weights.astype(jnp.float32)[:, None],
+        do_entity, do_global, num_entities,
+    )
+
+
+@partial(jax.jit, static_argnames=_AGG_STATIC)
+def _ragged_aggregate_q8(
+    q, scales, weights, member, do_entity, do_global, *, num_entities,
+    tile_p, use_pallas, interpret,
+):
+    if use_pallas:
+        return ragged_quantized_tiered_aggregate_pallas(
+            q, scales, weights, member, do_entity, do_global, num_entities,
+            tile_p=tile_p, interpret=interpret,
+        )
+    return q8_tiles_apply(
+        ragged_q8_tile, q, scales, tile_p,
+        weights.astype(jnp.float32)[:, None],
+        member.astype(jnp.float32)[:, None],
+        do_entity, do_global, num_entities,
+    )
+
+
 def tiered_aggregate_q8(
     x: jax.Array,
     weights: jax.Array,
@@ -66,7 +105,7 @@ def tiered_aggregate_q8(
     tile_p: int = TILE_P,
     key: Optional[jax.Array] = None,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Quantize [N, P] to the q8 wire format, aggregate fused, return f32.
 
@@ -74,30 +113,20 @@ def tiered_aggregate_q8(
     it the path is deterministic, which is what the bit-for-bit oracle
     tests and the engine-equality tests pin.
 
-    The ``use_pallas=False`` fallback dequantizes vectorized and reuses the
-    f32 reference reduction (the per-tile ``ref.py`` loop is the *test
-    oracle* — tracing it inside jit would unroll O(P/tile_p) subgraphs).
+    The ``use_pallas=False`` fallback vmaps the oracle's per-tile
+    arithmetic (``ref.q8_tile``) over all tiles at once (the per-tile
+    ``ref.py`` loop is the *test oracle* — tracing it inside jit would
+    unroll O(P/tile_p) subgraphs).
     """
-    N, P = x.shape
-    do_entity = jnp.asarray(do_entity)
-    do_global = jnp.asarray(do_global)
-    q, scales = q8_quantize(x.astype(jnp.float32), tile_p, key=key)
-    if use_pallas:
-        out = quantized_tiered_aggregate_pallas(
-            q, scales, weights, do_entity, do_global, num_entities,
-            tile_p=tile_p, interpret=interpret,
-        )
-    else:
-        deq = q8_dequantize(q, scales, tile_p)
-        out = tiered_aggregate_ref(
-            deq, weights, do_entity, do_global, num_entities
-        )
-    return out[:, :P]
+    q, scales = _q8_wire(x.astype(jnp.float32), tile_p, key=key)
+    out = _aggregate_q8(
+        q, scales, weights, jnp.asarray(do_entity), jnp.asarray(do_global),
+        num_entities=num_entities, tile_p=tile_p, use_pallas=use_pallas,
+        interpret=interpret,
+    )
+    return out[:, : x.shape[1]]
 
 
-@partial(
-    jax.jit, static_argnames=("num_entities", "tile_p", "use_pallas", "interpret")
-)
 def ragged_tiered_aggregate_q8(
     x: jax.Array,
     weights: jax.Array,
@@ -108,7 +137,7 @@ def ragged_tiered_aggregate_q8(
     tile_p: int = TILE_P,
     key: Optional[jax.Array] = None,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Ragged (per-class cut) q8 aggregation of an [N, P] unit-range shard.
 
@@ -116,44 +145,16 @@ def ragged_tiered_aggregate_q8(
     in the aggregating tier (``tiers.class_tier_members`` column); they
     alone feed and receive the two reduction levels.  All-ones member with
     normalized weights reproduces ``tiered_aggregate_q8`` bit-for-bit.
-    The ``use_pallas=False`` fallback dequantizes vectorized and applies
-    the member-masked reduction in one pass (the per-tile ``ref.py`` loop
-    stays the test oracle).
+    The ``use_pallas=False`` fallback vmaps ``ref.ragged_q8_tile`` over all
+    tiles at once (the per-tile ``ref.py`` loop stays the test oracle).
     """
-    N, P = x.shape
-    do_entity = jnp.asarray(do_entity)
-    do_global = jnp.asarray(do_global)
-    q, scales = q8_quantize(x.astype(jnp.float32), tile_p, key=key)
-    if use_pallas:
-        out = ragged_quantized_tiered_aggregate_pallas(
-            q, scales, weights, member, do_entity, do_global, num_entities,
-            tile_p=tile_p, interpret=interpret,
-        )
-    else:
-        deq = q8_dequantize(q, scales, tile_p)
-        J = num_entities
-        per = N // J
-        m = member.astype(jnp.float32)[:, None]
-        grouped = deq.reshape(J, per, -1)
-        mg = m.reshape(J, per, 1)
-        sg = jnp.sum(mg, axis=1, keepdims=True)
-        emean = jnp.sum(grouped * mg, axis=1, keepdims=True) / jnp.maximum(
-            sg, 1.0
-        )
-        emean = jnp.broadcast_to(emean, grouped.shape).reshape(deq.shape)
-        sg_rows = jnp.broadcast_to(sg, grouped.shape).reshape(deq.shape)
-        y1 = jnp.where(do_entity & (m > 0.0) & (sg_rows > 0.0), emean, deq)
-        wm = weights.astype(jnp.float32)[:, None] * m
-        sw = jnp.sum(wm, axis=0, keepdims=True)
-        gmean = jnp.sum(y1 * wm, axis=0, keepdims=True) / jnp.where(
-            sw > 0.0, sw, 1.0
-        )
-        out = jnp.where(
-            do_global & (m > 0.0) & (sw > 0.0),
-            jnp.broadcast_to(gmean, y1.shape),
-            y1,
-        )
-    return out[:, :P]
+    q, scales = _q8_wire(x.astype(jnp.float32), tile_p, key=key)
+    out = _ragged_aggregate_q8(
+        q, scales, weights, member, jnp.asarray(do_entity),
+        jnp.asarray(do_global), num_entities=num_entities, tile_p=tile_p,
+        use_pallas=use_pallas, interpret=interpret,
+    )
+    return out[:, : x.shape[1]]
 
 
 def aggregate_tree(
@@ -164,7 +165,7 @@ def aggregate_tree(
     num_entities: int,
     tile_p: int = TILE_P,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
     quantized: bool = False,
 ) -> Any:
     """Apply the fused aggregation leaf-wise to a client-stacked pytree.

@@ -19,6 +19,11 @@ single HBM read is ~4× cheaper than the f32 path.  Each grid step's scale
 column is a blocked VMEM input next to its int8 tile (the full scale array
 is O(P) — too big for SMEM); ``ref.py`` carries the tile-mirroring oracle
 the interpret-mode tests pin bit-for-bit.
+
+Every kernel compiles for TPU v5e at real leaf sizes (N = 20, J = 5,
+P in the millions; ``tests/test_tpu_compile.py``).  Only the round flags
+ride SMEM scalar prefetch: SMEM serves scalar loads only, so the O(N)
+weight and membership vectors are whole-array [N, 1] VMEM blocks.
 """
 from __future__ import annotations
 
@@ -33,7 +38,7 @@ TILE_P = 2048
 
 
 def _kernel(flags_ref, w_ref, x_ref, o_ref, *, num_entities: int):
-    """flags_ref: SMEM [2] int32; w_ref: SMEM [N] f32; x/o: VMEM [N, TP]."""
+    """flags_ref: SMEM [2] int32; w_ref: VMEM [N, 1] f32; x/o: VMEM [N, TP]."""
     x = x_ref[...].astype(jnp.float32)  # [N, TP]
     N = x.shape[0]
     J = num_entities
@@ -46,7 +51,7 @@ def _kernel(flags_ref, w_ref, x_ref, o_ref, *, num_entities: int):
     emean = jnp.broadcast_to(emean, grouped.shape).reshape(x.shape)
     y1 = jnp.where(do_entity, emean, x)
 
-    w = w_ref[...].astype(jnp.float32)[:, None]  # [N, 1]
+    w = w_ref[...]  # [N, 1]
     gmean = jnp.sum(y1 * w, axis=0, keepdims=True)
     y2 = jnp.where(do_global, jnp.broadcast_to(gmean, y1.shape), y1)
     o_ref[...] = y2.astype(o_ref.dtype)
@@ -74,28 +79,38 @@ def tiered_aggregate_pallas(
     out = pl.pallas_call(
         functools.partial(_kernel, num_entities=num_entities),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # flags, weights
+            num_scalar_prefetch=1,  # flags
             grid=grid,
-            in_specs=[pl.BlockSpec((N, tile_p), lambda i, *_: (0, i))],
+            in_specs=[
+                pl.BlockSpec((N, 1), lambda i, *_: (0, 0)),  # weights
+                pl.BlockSpec((N, tile_p), lambda i, *_: (0, i)),
+            ],
             out_specs=pl.BlockSpec((N, tile_p), lambda i, *_: (0, i)),
         ),
         out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
         interpret=interpret,
-    )(flags, weights.astype(jnp.float32), xp)
+    )(flags, weights.astype(jnp.float32)[:, None], xp)
     return out[:, :P] if pad else out
 
 
+def _scale_columns(scales: jax.Array) -> jax.Array:
+    """[N, T] per-tile scales -> [T, N, 1]: grid step i reads the whole
+    [N, 1] column of tile i as a block (a lone column cut from the lane
+    axis is not a legal TPU block)."""
+    return scales.astype(jnp.float32).T[:, :, None]
+
+
 def _q8_kernel(flags_ref, w_ref, q_ref, s_ref, o_ref, *, num_entities: int):
-    """flags/w in SMEM ([2] i32, [N] f32); q [N, TP] i8 and this tile's
+    """flags in SMEM ([2] i32); w [N, 1] f32, q [N, TP] i8 and this tile's
     scale column s [N, 1] f32 in VMEM; o VMEM [N, TP] f32.
 
     One fused pass per tile: int8 → f32 dequant against the tile's scale
     column, then the same two-level (Eq. 3 + Eq. 4) reduction as
     ``_kernel``.  Scales are a *blocked* input, not scalar prefetch — the
     full [N, P/tile_p] scale array is O(P) and would blow SMEM on real
-    leaves; only the O(N) flags/weights ride the prefetch path.  The op
-    sequence is mirrored verbatim by ``ref.quantized_tiered_aggregate_ref``
-    so interpret mode matches the oracle bit-for-bit.
+    leaves.  SMEM only serves scalar loads, so the O(N) weights are a
+    whole-array VMEM block.  The op sequence is mirrored verbatim by
+    ``ref.q8_tile`` so interpret mode matches the oracle bit-for-bit.
     """
     s = s_ref[...].astype(jnp.float32)            # [N, 1]
     x = q_ref[...].astype(jnp.float32) * s        # dequantized [N, TP]
@@ -110,7 +125,7 @@ def _q8_kernel(flags_ref, w_ref, q_ref, s_ref, o_ref, *, num_entities: int):
     emean = jnp.broadcast_to(emean, grouped.shape).reshape(x.shape)
     y1 = jnp.where(do_entity, emean, x)
 
-    w = w_ref[...].astype(jnp.float32)[:, None]  # [N, 1]
+    w = w_ref[...]  # [N, 1]
     gmean = jnp.sum(y1 * w, axis=0, keepdims=True)
     y2 = jnp.where(do_global, jnp.broadcast_to(gmean, y1.shape), y1)
     o_ref[...] = y2
@@ -121,7 +136,7 @@ def _ragged_q8_kernel(
 ):
     """Ragged (per-class cut) variant of ``_q8_kernel`` (DESIGN.md §14).
 
-    ``m_ref`` (SMEM [N] f32, 0/1) marks the clients whose class holds this
+    ``m_ref`` (VMEM [N, 1] f32, 0/1) marks the clients whose class holds this
     shard's units in the aggregating tier.  Non-members neither contribute
     to nor receive either reduction level — their replica of these units
     belongs to a different tier and is aggregated by that tier's schedule:
@@ -135,29 +150,33 @@ def _ragged_q8_kernel(
     With member ≡ 1 and weights already normalized (Σ w = 1, exact for
     uniform 1/N at power-of-two N) every guard divide is by 1.0 or the
     exact group size, so the result is bit-identical to ``_q8_kernel`` —
-    the collapse the interpret-mode tests pin.  Mirrored per tile by
-    ``ref.ragged_quantized_tiered_aggregate_ref``.
+    the collapse the interpret-mode tests pin.  The member mask is folded
+    into the scale (``s·1 == s`` exactly), so the masked entity sum
+    dequantizes exactly as ``_q8_kernel``'s does.  Mirrored per tile by
+    ``ref.ragged_q8_tile``.
     """
     s = s_ref[...].astype(jnp.float32)            # [N, 1]
-    x = q_ref[...].astype(jnp.float32) * s        # dequantized [N, TP]
+    member = m_ref[...]                           # [N, 1]
+    qf = q_ref[...].astype(jnp.float32)
+    x = qf * s                                    # dequantized [N, TP]
+    xm = qf * (s * member)                        # member-masked dequant
     N = x.shape[0]
     J = num_entities
     per = N // J
     do_entity = flags_ref[0] > 0
     do_global = flags_ref[1] > 0
-    member = m_ref[...].astype(jnp.float32)[:, None]   # [N, 1]
+    TP = x.shape[1]
 
-    grouped = x.reshape(J, per, x.shape[1])
     mg = member.reshape(J, per, 1)
     sg = jnp.sum(mg, axis=1, keepdims=True)            # [J, 1, 1]
-    emean = jnp.sum(grouped * mg, axis=1, keepdims=True) / jnp.maximum(
+    emean = jnp.sum(xm.reshape(J, per, TP), axis=1, keepdims=True) / jnp.maximum(
         sg, 1.0
     )
-    emean = jnp.broadcast_to(emean, grouped.shape).reshape(x.shape)
-    sg_rows = jnp.broadcast_to(sg, grouped.shape).reshape(x.shape)
+    emean = jnp.broadcast_to(emean, (J, per, TP)).reshape(x.shape)
+    sg_rows = jnp.broadcast_to(sg, (J, per, TP)).reshape(x.shape)
     y1 = jnp.where(do_entity & (member > 0.0) & (sg_rows > 0.0), emean, x)
 
-    wm = w_ref[...].astype(jnp.float32)[:, None] * member  # [N, 1]
+    wm = w_ref[...] * member                               # [N, 1]
     sw = jnp.sum(wm, axis=0, keepdims=True)                # [1, 1]
     gmean = jnp.sum(y1 * wm, axis=0, keepdims=True) / jnp.where(
         sw > 0.0, sw, 1.0
@@ -197,17 +216,18 @@ def quantized_tiered_aggregate_pallas(
     return pl.pallas_call(
         functools.partial(_q8_kernel, num_entities=num_entities),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # flags, weights (O(N) only)
+            num_scalar_prefetch=1,  # flags
             grid=grid,
             in_specs=[
+                pl.BlockSpec((N, 1), lambda i, *_: (0, 0)),  # weights
                 pl.BlockSpec((N, tile_p), lambda i, *_: (0, i)),
-                pl.BlockSpec((N, 1), lambda i, *_: (0, i)),  # scale column
+                pl.BlockSpec((None, N, 1), lambda i, *_: (i, 0, 0)),  # scales
             ],
             out_specs=pl.BlockSpec((N, tile_p), lambda i, *_: (0, i)),
         ),
         out_shape=jax.ShapeDtypeStruct((N, Pp), jnp.float32),
         interpret=interpret,
-    )(flags, weights.astype(jnp.float32), q, scales.astype(jnp.float32))
+    )(flags, weights.astype(jnp.float32)[:, None], q, _scale_columns(scales))
 
 
 def ragged_quantized_tiered_aggregate_pallas(
@@ -242,11 +262,13 @@ def ragged_quantized_tiered_aggregate_pallas(
     return pl.pallas_call(
         functools.partial(_ragged_q8_kernel, num_entities=num_entities),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # flags, weights, member (all O(N))
+            num_scalar_prefetch=1,  # flags
             grid=grid,
             in_specs=[
+                pl.BlockSpec((N, 1), lambda i, *_: (0, 0)),  # weights
+                pl.BlockSpec((N, 1), lambda i, *_: (0, 0)),  # member
                 pl.BlockSpec((N, tile_p), lambda i, *_: (0, i)),
-                pl.BlockSpec((N, 1), lambda i, *_: (0, i)),  # scale column
+                pl.BlockSpec((None, N, 1), lambda i, *_: (i, 0, 0)),  # scales
             ],
             out_specs=pl.BlockSpec((N, tile_p), lambda i, *_: (0, i)),
         ),
@@ -254,8 +276,8 @@ def ragged_quantized_tiered_aggregate_pallas(
         interpret=interpret,
     )(
         flags,
-        weights.astype(jnp.float32),
-        member.astype(jnp.float32),
+        weights.astype(jnp.float32)[:, None],
+        member.astype(jnp.float32)[:, None],
         q,
-        scales.astype(jnp.float32),
+        _scale_columns(scales),
     )

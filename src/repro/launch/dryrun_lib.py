@@ -375,8 +375,6 @@ def run_case(case: DryrunCase, mesh=None, compile_: bool = True) -> Dict[str, An
     meta["compile_s"] = round(time.time() - t1, 2)
 
     ca_ = compiled.cost_analysis() or {}
-    if isinstance(ca_, (list, tuple)):  # jax 0.4.x returns [dict], newer a dict
-        ca_ = ca_[0] if ca_ else {}
     meta["flops"] = float(ca_.get("flops", 0.0))
     meta["bytes_accessed"] = float(ca_.get("bytes accessed", 0.0))
     spec, shape = _spec_for(case)

@@ -14,15 +14,24 @@ MA solver prices with DCN (not ICI) constants.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 POD_SHAPE = (16, 16)
 MULTIPOD_SHAPE = (2, 16, 16)
 
 
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the compiler propagates
+    shardings from the placed inputs and the ``shard_map`` specs.  (Its
+    default, ``Explicit``, carries shardings in the types, which this
+    repo's unannotated gathers and reshapes do not.)"""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTIPOD_SHAPE if multi_pod else POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def client_axes(multi_pod: bool = False):
@@ -63,5 +72,5 @@ def make_debug_mesh(data: int = 2, model: int = 2, pods: int = 0):
             f"flag exported, as tests/test_sharded_exec.py does."
         )
     if pods:
-        return jax.make_mesh((pods, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pods, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

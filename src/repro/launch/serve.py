@@ -67,7 +67,9 @@ def main(argv=None) -> int:
 
     from ..configs import get_reduced
     from ..models.model import SplittableModel
+    from .compile_cache import configure_compile_cache
 
+    configure_compile_cache()
     spec = get_reduced(args.arch)
     if spec.family in ("vlm", "audio"):
         raise SystemExit(f"{args.arch}: decode driver supports text-only archs")

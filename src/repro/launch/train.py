@@ -22,7 +22,17 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def main(argv=None) -> int:
+def fed_round(intervals, r: int) -> tuple:
+    """Which tiers sync at the fed server after round ``r`` (0-based)."""
+    return tuple((r + 1) % I == 0 if I > 1 else True for I in intervals)
+
+
+def train(argv=None) -> dict:
+    """Parse ``argv``, train, and return the run's record: the final
+    ``state`` and ``plan``, the per-round ``losses``, the wall
+    ``round_seconds`` of each round (measured once its outputs are
+    ready), and ``warm``, True for a round whose program had run before
+    (False where the round compiled)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="vgg16-cifar10")
     ap.add_argument("--rounds", type=int, default=100)
@@ -57,6 +67,10 @@ def main(argv=None) -> int:
                          "tier; 0 is the synchronous schedule "
                          "(core.async_agg)")
     args = ap.parse_args(argv)
+
+    from .compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     from ..configs import get_reduced
     from ..core import (
@@ -142,8 +156,7 @@ def main(argv=None) -> int:
         cache = {}
 
         def dispatch(state_, batch_, r):
-            fed = tuple((r + 1) % I == 0 if I > 1 else True
-                        for I in plan_.intervals)
+            fed = fed_round(plan_.intervals, r)
             if fed not in cache:
                 cache[fed] = jax.jit(
                     build_train_step_a(model, plan_, opt, fed_round=fed)
@@ -206,13 +219,20 @@ def main(argv=None) -> int:
           f"I={plan.intervals} N={args.clients} J2={args.edges}"
           + (f"  [{', '.join(mode)}]" if mode else ""))
     dispatch, trainer = make_dispatch(plan)
-    t0 = time.time()
+    losses, round_seconds, warm, seen = [], [], [], set()
     for r in range(args.rounds):
         batch = {k: jnp.asarray(v) for k, v in loader.next_round().items()}
+        fed = fed_round(plan.intervals, r)
+        t0 = time.perf_counter()
         state, loss = dispatch(state, batch, r)
+        jax.block_until_ready((state, loss))
+        round_seconds.append(time.perf_counter() - t0)
+        losses.append(float(loss))
+        warm.append(fed in seen)
+        seen.add(fed)
         if (r + 1) % args.log_every == 0 or r == 0:
-            print(f"round {r+1:5d}  loss {float(loss):.4f}  "
-                  f"({(time.time()-t0)/(r+1):.2f}s/round)")
+            print(f"round {r+1:5d}  loss {losses[-1]:.4f}  "
+                  f"({round_seconds[-1]:.3f}s)")
     if trainer is not None:
         state = trainer.drain(state)  # fold in-flight async syncs in
 
@@ -224,6 +244,14 @@ def main(argv=None) -> int:
             meta={"cuts": list(plan.cuts), "intervals": list(plan.intervals)},
         )
         print(f"saved checkpoint -> {args.checkpoint}")
+    return {
+        "state": state, "plan": plan, "losses": losses,
+        "round_seconds": round_seconds, "warm": warm,
+    }
+
+
+def main(argv=None) -> int:
+    train(argv)
     return 0
 
 

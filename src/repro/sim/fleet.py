@@ -12,8 +12,9 @@ duration arrays as the event core (``events.round_stage_durations``) and
 accumulates them in the same canonical order, so for any trace and cut
 vector ``simulate_rounds`` and ``events.simulate`` agree to the last bit —
 ``tests/test_sim.py`` enforces this on every scenario.  The JAX backend
-runs under ``jax.experimental.enable_x64`` (float64 elementwise IEEE ops
-match NumPy exactly); straggler quantiles are ``jnp`` reductions.
+runs under ``core.batched.x64_scope``, on the host CPU (float64
+elementwise IEEE ops match NumPy exactly); straggler quantiles are
+``jnp`` reductions.
 """
 from __future__ import annotations
 
@@ -25,14 +26,9 @@ import numpy as np
 from .events import fires, round_agg_phases, round_stage_durations
 from .scenarios import SystemTrace
 
-try:  # CPU jax is in the image; keep the subsystem importable without it
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
+import jax.numpy as jnp
 
-    _HAS_JAX = True
-except Exception:  # pragma: no cover - exercised only on jax-less installs
-    _HAS_JAX = False
+from ..core.batched import x64_scope
 
 
 @dataclass(frozen=True)
@@ -58,16 +54,14 @@ class FleetResult:
 
 def _resolve_backend(backend: str) -> str:
     if backend == "auto":
-        return "jax" if _HAS_JAX else "numpy"
-    if backend == "jax" and not _HAS_JAX:
-        raise RuntimeError("jax backend requested but jax is not importable")
+        return "jax"
     return backend
 
 
 def quantiles(x: np.ndarray, qs: Sequence[float], backend: str = "auto") -> np.ndarray:
     """Quantile reduction (jnp when available — the sim_scale hot path)."""
     if _resolve_backend(backend) == "jax":
-        with enable_x64():
+        with x64_scope():
             return np.asarray(jnp.quantile(jnp.asarray(x), jnp.asarray(list(qs))))
     return np.quantile(np.asarray(x), list(qs))
 
@@ -84,7 +78,7 @@ def round_latency(
     M = trace.system.M
 
     if be == "jax":
-        with enable_x64():
+        with x64_scope():
             t = jnp.zeros(trace.system.num_clients)
             for d in durs:
                 t = t + jnp.asarray(d)
@@ -107,7 +101,7 @@ def round_latency(
             continue
         up, down = phases
         if be == "jax":
-            with enable_x64():
+            with x64_scope():
                 agg[m] = float(jnp.max(jnp.asarray(up))) + float(
                     jnp.max(jnp.asarray(down))
                 )
@@ -200,7 +194,7 @@ def price_lattice_round(
     if not avail.any():
         pass  # a round with zero participants has split 0 (events.py)
     elif be == "jax":
-        with enable_x64():
+        with x64_scope():
             t = jnp.zeros((K, N))
             for s, rt in enumerate(rates):
                 t = t + jnp.asarray(works[:, s])[:, None] / jnp.asarray(rt)[None, :]

@@ -29,7 +29,7 @@ from repro.core import (
     solve_ms,
     synthetic_hyperspec,
 )
-from repro.core.batched import _HAS_JAX, resolve_backend
+from repro.core.batched import resolve_backend
 from repro.core.convergence import theorem1_bound
 from repro.core.latency import LayerProfile
 
@@ -158,7 +158,6 @@ def test_batched_matches_scalar_vgg_compressed():
     assert_evaluator_matches_scalar(problem, ev, [[2, 3, 1], [1, 1, 1]])
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not importable")
 def test_jax_tables_bit_equal_numpy():
     for comp in (None, CompressionSpec.uniform(3, 0.25, act_ratio=0.5)):
         prof = build_profile(VGG, batch=16)
@@ -368,9 +367,8 @@ def test_resolve_backend():
     assert resolve_backend("numpy") == "numpy"
     with pytest.raises(ValueError, match="unknown batched backend"):
         resolve_backend("cuda")
-    if _HAS_JAX:
-        assert resolve_backend("auto", work_elems=10) == "numpy"
-        assert resolve_backend("auto", work_elems=10**9) == "jax"
+    assert resolve_backend("auto", work_elems=10) == "numpy"
+    assert resolve_backend("auto", work_elems=10**9) == "jax"
 
 
 def test_solve_ma_rejects_unknown_backend():

@@ -32,7 +32,7 @@ CASES = [
 def test_forward_matches_ref(case):
     B, S, H, K, hd, W = case
     q, k, v = rand_qkv(jax.random.PRNGKey(sum(case)), B, S, H, K, hd)
-    out = swa_attention(q, k, v, window=W)
+    out = swa_attention(q, k, v, window=W, interpret=True)
     ref = swa_attention_ref(q, k, v, W)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
@@ -43,7 +43,7 @@ def test_backward_matches_ref(case):
     key = jax.random.PRNGKey(sum(case) + 1)
     q, k, v = rand_qkv(key, B, S, H, K, hd)
     dd = jax.random.normal(jax.random.fold_in(key, 9), q.shape)
-    g1 = jax.grad(lambda *a: jnp.sum(swa_attention(*a, window=W) * dd), (0, 1, 2))(q, k, v)
+    g1 = jax.grad(lambda *a: jnp.sum(swa_attention(*a, window=W, interpret=True) * dd), (0, 1, 2))(q, k, v)
     g2 = jax.grad(lambda *a: jnp.sum(swa_attention_ref(*a, W) * dd), (0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         scale = np.max(np.abs(np.asarray(b))) + 1e-9
@@ -55,7 +55,7 @@ def test_backward_matches_ref(case):
 def test_bfloat16_forward():
     B, S, H, K, hd, W = 1, 256, 4, 2, 64, 128
     q, k, v = rand_qkv(jax.random.PRNGKey(7), B, S, H, K, hd, jnp.bfloat16)
-    out = swa_attention(q, k, v, window=W)
+    out = swa_attention(q, k, v, window=W, interpret=True)
     ref = swa_attention_ref(
         q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32), W
     )
@@ -68,8 +68,8 @@ def test_window_equals_full_when_large():
     B, S, H, K, hd = 1, 256, 4, 2, 64
     q, k, v = rand_qkv(jax.random.PRNGKey(8), B, S, H, K, hd)
     np.testing.assert_allclose(
-        swa_attention(q, k, v, window=512),  # window >= S -> full causal
-        swa_attention(q, k, v, window=0),
+        swa_attention(q, k, v, window=512, interpret=True),  # window >= S -> full causal
+        swa_attention(q, k, v, window=0, interpret=True),
         rtol=1e-6,
     )
 
@@ -88,5 +88,5 @@ def test_matches_model_layer_semantics():
     q, k, v = rand_qkv(key, B, S, H, K, hd)
     bias = L._mask_bias(jnp.arange(S), jnp.arange(S), True, 128, 0)
     ref = L._sdpa(q, k, v, bias)
-    out = swa_attention(q, k, v, window=128)
+    out = swa_attention(q, k, v, window=128, interpret=True)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)

@@ -97,8 +97,10 @@ def test_q8_aggregation_close_to_lossless():
     key = jax.random.PRNGKey(9)
     x = jax.random.normal(key, (8, 700))
     w = jnp.full((8,), 1 / 8)
-    lossless = tiered_aggregate(x, w, jnp.array(1), jnp.array(1), 4)
-    q8 = tiered_aggregate_q8(x, w, jnp.array(1), jnp.array(1), 4, tile_p=128)
+    lossless = tiered_aggregate(x, w, jnp.array(1), jnp.array(1), 4, interpret=True)
+    q8 = tiered_aggregate_q8(
+        x, w, jnp.array(1), jnp.array(1), 4, tile_p=128, interpret=True
+    )
     lsb = float(jnp.max(jnp.abs(x))) / 127.0
     np.testing.assert_allclose(
         np.asarray(q8), np.asarray(lossless, np.float32), atol=lsb
@@ -113,9 +115,10 @@ def test_aggregate_tree_quantized_roundtrip():
     }
     w = jnp.full((8,), 1 / 8)
     out = aggregate_tree(
-        tree, w, jnp.array(1), jnp.array(0), 4, tile_p=128, quantized=True
+        tree, w, jnp.array(1), jnp.array(0), 4, tile_p=128, quantized=True,
+        interpret=True,
     )
-    ref = aggregate_tree(tree, w, jnp.array(1), jnp.array(0), 4)
+    ref = aggregate_tree(tree, w, jnp.array(1), jnp.array(0), 4, interpret=True)
     for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=0.05)
@@ -124,11 +127,11 @@ def test_aggregate_tree_quantized_roundtrip():
 def test_flags_semantics():
     x = jnp.arange(8.0).reshape(4, 2)
     w = jnp.full((4,), 0.25)
-    noop = tiered_aggregate(x, w, jnp.array(0), jnp.array(0), 2)
+    noop = tiered_aggregate(x, w, jnp.array(0), jnp.array(0), 2, interpret=True)
     np.testing.assert_allclose(noop, x)
-    glob = tiered_aggregate(x, w, jnp.array(0), jnp.array(1), 2)
+    glob = tiered_aggregate(x, w, jnp.array(0), jnp.array(1), 2, interpret=True)
     np.testing.assert_allclose(glob, jnp.broadcast_to(x.mean(0), x.shape), rtol=1e-6)
-    ent = tiered_aggregate(x, w, jnp.array(1), jnp.array(0), 2)
+    ent = tiered_aggregate(x, w, jnp.array(1), jnp.array(0), 2, interpret=True)
     np.testing.assert_allclose(ent[0], ent[1])
     np.testing.assert_allclose(ent[2], ent[3])
     assert not np.allclose(ent[0], ent[2])
@@ -138,7 +141,7 @@ def test_weighted_global_mean():
     key = jax.random.PRNGKey(3)
     x = jax.random.normal(key, (8, 100))
     w = jax.nn.softmax(jax.random.normal(jax.random.fold_in(key, 1), (8,)))
-    out = tiered_aggregate(x, w, jnp.array(0), jnp.array(1), 4)
+    out = tiered_aggregate(x, w, jnp.array(0), jnp.array(1), 4, interpret=True)
     expect = jnp.sum(x * w[:, None], axis=0)
     np.testing.assert_allclose(out[3], expect, rtol=1e-5, atol=1e-6)
 
@@ -153,7 +156,7 @@ def test_aggregate_tree_matches_synchronize_level():
         "b": {"c": jax.random.normal(jax.random.fold_in(key, 1), (8, 7))},
     }
     w = jnp.full((8,), 1 / 8)
-    out = aggregate_tree(tree, w, jnp.array(1), jnp.array(0), 4)
+    out = aggregate_tree(tree, w, jnp.array(1), jnp.array(0), 4, interpret=True)
     ref = _group_mean(tree, 4)
     for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)

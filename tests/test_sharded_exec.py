@@ -22,6 +22,7 @@ SCRIPT = textwrap.dedent("""
 
     from repro.configs import get_reduced
     from repro.launch import sharding as sh
+    from repro.launch.mesh import make_mesh
     from repro.models.model import SplittableModel
 
     assert len(jax.devices()) == 8
@@ -39,7 +40,7 @@ SCRIPT = textwrap.dedent("""
         params, tok, caches0, jnp.int32(0)
     )
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     pps = sh.param_pspecs(params, tp=4, client_axes=None)
     params_sh = jax.device_put(params, sh.to_shardings(mesh, pps))
     outs = {}
@@ -83,6 +84,7 @@ MOE_SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import get_reduced
+    from repro.launch.mesh import make_mesh
     from repro.models import layers as L
 
     spec = get_reduced("granite-moe-1b-a400m")
@@ -92,7 +94,7 @@ MOE_SCRIPT = textwrap.dedent("""
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, spec.d_model))
     ref, _ = L.moe(p, x, spec, groups=1)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     def constraint(b):
         g, e = b.shape[0], b.shape[1]
         pg = "data" if g % 2 == 0 else None

@@ -1,0 +1,144 @@
+"""Ahead-of-time compiles for a described TPU v5e chip.
+
+Nothing runs: the TPU compiler that is installed with JAX compiles for a
+chip that is described, not attached, and refuses here what the chip
+would refuse (block shapes off the tiling, too much fast memory, a
+program that does not fit).  Covered: the three ``tiered_aggregate``
+kernels at real leaf widths (N = 20 clients, J = 5 edges), the SWA
+attention kernels forward and backward at SmolLM's head dim, and the
+jitted VGG-16/CIFAR-10 Engine-A local-round and sync-round steps at
+20 clients.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+N, J = 20, 5
+# a VGG-16 block-5 conv kernel (3·3·512·512) and SmolLM-135M's tied
+# embedding (49 152·576): the largest leaves the sync kernels would see
+LEAVES = {"vgg_conv": 3 * 3 * 512 * 512, "smollm_embed": 49_152 * 576}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+    return compiled
+
+
+@pytest.mark.parametrize("leaf", sorted(LEAVES))
+@pytest.mark.parametrize("kind", ["f32", "q8", "ragged_q8"])
+def test_tiered_aggregate_kernel_compiles(one_chip, kind, leaf):
+    from repro.kernels.tiered_aggregate.tiered_aggregate import (
+        TILE_P,
+        quantized_tiered_aggregate_pallas,
+        ragged_quantized_tiered_aggregate_pallas,
+        tiered_aggregate_pallas,
+    )
+
+    P = LEAVES[leaf]
+    Pp = -(-P // TILE_P) * TILE_P
+    s = lambda shape, dt: _sds(one_chip, shape, dt)
+    flag = s((), jnp.int32)
+    w = s((N,), jnp.float32)
+    if kind == "f32":
+        c = _compile(
+            lambda x, w, a, b: tiered_aggregate_pallas(x, w, a, b, J),
+            s((N, P), jnp.float32), w, flag, flag,
+        )
+    elif kind == "q8":
+        c = _compile(
+            lambda q, sc, w, a, b: quantized_tiered_aggregate_pallas(
+                q, sc, w, a, b, J
+            ),
+            s((N, Pp), jnp.int8), s((N, Pp // TILE_P), jnp.float32), w,
+            flag, flag,
+        )
+    else:
+        c = _compile(
+            lambda q, sc, w, m, a, b: ragged_quantized_tiered_aggregate_pallas(
+                q, sc, w, m, a, b, J
+            ),
+            s((N, Pp), jnp.int8), s((N, Pp // TILE_P), jnp.float32), w, w,
+            flag, flag,
+        )
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_swa_attention_kernel_compiles(one_chip, direction):
+    from repro.kernels.swa_attention import swa_attention
+
+    # SmolLM-135M attention: 9 query heads over 3 KV heads of dim 64
+    B, S, H, K, hd, W = 1, 8192, 9, 3, 64, 4096
+    q = _sds(one_chip, (B, S, H, hd), jnp.bfloat16)
+    kv = _sds(one_chip, (B, S, K, hd), jnp.bfloat16)
+    fwd = lambda q, k, v: swa_attention(q, k, v, window=W)
+    if direction == "fwd":
+        fn = fwd
+    else:
+        fn = jax.grad(
+            lambda q, k, v: jnp.sum(fwd(q, k, v).astype(jnp.float32)), (0, 1, 2)
+        )
+    assert "tpu_custom_call" in _compile(fn, q, kv, kv).as_text()
+
+
+@pytest.mark.parametrize(
+    "fed", [(False, False, True), (False, True, True)], ids=["local", "sync"]
+)
+def test_vgg_engine_a_step_compiles(one_chip, fed):
+    from repro.configs.vgg16_cifar10 import SPEC
+    from repro.core import build_train_step_a, init_state_a
+    from repro.core.tiers import default_plan
+    from repro.models.vgg import build_model
+    from repro.optim import sgd
+
+    model, opt = build_model(SPEC), sgd(5e-4)
+    plan = default_plan(SPEC.n_units, N, entities=(N, J, 1))
+    state = jax.eval_shape(
+        lambda: init_state_a(model, plan, opt, jax.random.PRNGKey(0))
+    )
+    state = jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), state)
+    batch = {
+        "images": _sds(one_chip, (N, 16, 32, 32, 3), jnp.float32),
+        "labels": _sds(one_chip, (N, 16), jnp.int32),
+    }
+    step = build_train_step_a(model, plan, opt, fed_round=fed)
+    mem = _compile(step, state, batch).memory_analysis()
+    # 20 f32 replicas of VGG-16's ~15 M parameters, plus the batch
+    assert 1.0e9 < mem.argument_size_in_bytes < 1.5e9
