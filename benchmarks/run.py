@@ -1,5 +1,5 @@
-"""Benchmark runner: one harness per paper table/figure, the roofline
-extraction over the dry-run artifacts, and the fleet-simulator scale sweep.
+"""Benchmark runner: one harness per paper table/figure and the
+fleet-simulator scale sweep.
 
     PYTHONPATH=src python -m benchmarks.run [names...] [--quick] [--seed S]
                                             [--skip-training] [--list]
@@ -43,7 +43,6 @@ Harness -> paper artifact map (details in DESIGN.md §7):
                                      async-vs-sync round pricing, staleness-
                                      inflated Thm 1 envelope, CPU rehearsal
                                      of a sharded round
-    roofline              §g         three-term roofline per (arch x shape)
 """
 from __future__ import annotations
 
@@ -120,7 +119,7 @@ def _registry(args):
         ablations, async_scale, bound_check, compress_sweep, control_drift,
         fault_tolerance, fig2_latency_vs_cut, fig45_benchmarks,
         fig67_resources, heterogeneous_cuts, participation_sweep,
-        privacy_energy, roofline, sim_scale, solver_scale,
+        privacy_energy, sim_scale, solver_scale,
     )
 
     return [
@@ -159,18 +158,7 @@ def _registry(args):
         # a 10^6-client overlap sweep, and a CPU-held sharded subprocess round)
         ("async_scale", "training",
          lambda: async_scale.main(args.quick, seed=args.seed)),
-        ("roofline", "extracted", lambda: _roofline(roofline)),
     ]
-
-
-def _roofline(roofline):
-    import os
-
-    if not os.path.isdir("experiments/dryrun"):
-        print("roofline skipped: no dry-run artifacts under experiments/ "
-              "(produce them with `python -m repro.launch.dryrun` first)")
-        return []
-    return roofline.main(["--csv", "experiments/roofline_16x16.csv"])
 
 
 def main(argv=None) -> int:

@@ -21,6 +21,7 @@ _SUBMODULES = (
     "kernels",
     "launch",
     "models",
+    "obs",
     "optim",
     "privacy",
     "sim",

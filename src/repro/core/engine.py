@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs
 from ..optim import Optimizer
 from .tiers import (
     GuardSpec,
@@ -208,10 +209,12 @@ def build_train_step_a(
         )
 
     def _step(state: TrainState, batch: Params, mask) -> Tuple[TrainState, jax.Array]:
-        losses, grads = jax.vmap(jax.value_and_grad(model.loss_fn))(
-            state.params, batch
-        )
-        new_params, new_opt = opt.update(state.params, grads, state.opt_state)
+        with obs.scope(obs.GRAD):
+            losses, grads = jax.vmap(jax.value_and_grad(model.loss_fn))(
+                state.params, batch
+            )
+        with obs.scope(obs.OPT):
+            new_params, new_opt = opt.update(state.params, grads, state.opt_state)
         if guard is not None:
             # Guarded step (DESIGN.md §16): quarantine clients whose update
             # went non-finite or blew up in norm.  Their local update is

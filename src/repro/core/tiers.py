@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs
+
 Params = Dict[str, Any]
 
 
@@ -406,14 +408,15 @@ def synchronize(
                     return _group_mean_masked(p, groups, mask, keep=original)
                 return _group_mean(p, groups)
 
-            if interval <= 1:
-                part = level_mean(part)
-            elif fed_round is None:
-                do = (step + 1) % interval == 0
-                part = lax.cond(do, level_mean, lambda p: p, part)
-            elif fed_round[m]:
-                part = level_mean(part)
-            # fed_round[m] is False -> skip tier m's fed-server level
+            with obs.scope(obs.sync_level(m, li, len(levels))):
+                if interval <= 1:
+                    part = level_mean(part)
+                elif fed_round is None:
+                    do = (step + 1) % interval == 0
+                    part = lax.cond(do, level_mean, lambda p: p, part)
+                elif fed_round[m]:
+                    part = level_mean(part)
+                # fed_round[m] is False -> skip tier m's fed-server level
         out_parts.append(part)
     return combine_tiers(out_parts, params)
 

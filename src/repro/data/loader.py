@@ -11,6 +11,8 @@ from typing import Dict, Iterator, List, Optional
 
 import numpy as np
 
+from .. import obs
+
 
 class FederatedLoader:
     def __init__(
@@ -31,13 +33,14 @@ class FederatedLoader:
 
     def next_round(self) -> Dict[str, np.ndarray]:
         """One client-stacked batch {key: [N, b, ...]}."""
-        idx = np.stack(
-            [
-                self._rng.choice(part, size=self.batch, replace=len(part) < self.batch)
-                for part in self.partitions
-            ]
-        )  # [N, b]
-        return {k: v[idx] for k, v in self.arrays.items()}
+        with obs.host_span(obs.LOADER):
+            idx = np.stack(
+                [
+                    self._rng.choice(part, size=self.batch, replace=len(part) < self.batch)
+                    for part in self.partitions
+                ]
+            )  # [N, b]
+            return {k: v[idx] for k, v in self.arrays.items()}
 
     def rounds(self, n: int) -> Iterator[Dict[str, np.ndarray]]:
         for _ in range(n):

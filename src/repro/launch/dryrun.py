@@ -11,9 +11,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
         [--dtype bfloat16] [--out experiments/dryrun]
 
 Lowers + compiles the requested (architecture × input-shape × mesh) case,
-prints memory_analysis() / cost_analysis(), and writes the JSON record the
-roofline benchmark consumes. ``--mesh multipod`` proves the `pod` axis
-shards (2×16×16 = 512 chips); the roofline table itself is single-pod.
+prints memory_analysis() / cost_analysis(), and writes its JSON record.
+``--mesh multipod`` proves the `pod` axis shards (2×16×16 = 512 chips).
 """
 import argparse
 import json
