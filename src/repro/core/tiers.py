@@ -199,18 +199,21 @@ def _slice_units(units: Any, lo: int, hi: int) -> Any:
     return jax.tree.map(lambda x: x[:, lo:hi], units)
 
 
+def _tier_part(params: Params, plan: TierPlan, m: int) -> Params:
+    """Tier m's pytree: its unit range, plus the frontend (tier 1) and the
+    head (tier M)."""
+    lo, hi = plan.tier_bounds(m)
+    part: Params = {"units": _slice_units(params["units"], lo, hi)}
+    if m == 0:
+        part["frontend"] = params["frontend"]
+    if m == plan.M - 1:
+        part["head"] = params["head"]
+    return part
+
+
 def tier_subtrees(params: Params, plan: TierPlan) -> List[Params]:
     """Split a client-stacked model pytree into per-tier pytrees (views)."""
-    parts: List[Params] = []
-    for m in range(plan.M):
-        lo, hi = plan.tier_bounds(m)
-        part: Params = {"units": _slice_units(params["units"], lo, hi)}
-        if m == 0:
-            part["frontend"] = params["frontend"]
-        if m == plan.M - 1:
-            part["head"] = params["head"]
-        parts.append(part)
-    return parts
+    return [_tier_part(params, plan, m) for m in range(plan.M)]
 
 
 def combine_tiers(parts: List[Params], template: Params) -> Params:
@@ -235,6 +238,43 @@ def _concat_stacks(stacks: List[Any]) -> Any:
     if len(stacks) == 1:
         return stacks[0]
     return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=1), *stacks)
+
+
+def _update_units(units: Any, part: Any, lo: int, hi: int) -> Any:
+    """Write ``part`` (a ``_slice_units(units, lo, hi)`` result) back into
+    the range [lo, hi) of ``units``.  A list has its entries replaced; a
+    stacked leaf is updated along the unit axis with a static-start
+    ``dynamic_update_slice``, which XLA performs in place on a buffer
+    nothing else reads — no concatenate copies the other tiers."""
+    if isinstance(units, (list, tuple)):
+        out = list(units)
+        out[lo:hi] = part
+        return out
+
+    def put(stack, new, start):
+        return jax.tree.map(
+            lambda x, p: lax.dynamic_update_slice_in_dim(x, p, start, axis=1),
+            stack, new,
+        )
+
+    if isinstance(units, dict) and set(units) == {"enc", "dec"}:
+        ne = jax.tree.leaves(units["enc"])[0].shape[1]
+        return {
+            "enc": put(units["enc"], part["enc"], min(lo, ne)),
+            "dec": put(units["dec"], part["dec"], max(lo, ne) - ne),
+        }
+    return put(units, part, lo)
+
+
+def _put_tier(params: Params, part: Params, plan: TierPlan, m: int) -> Params:
+    """Inverse of ``_tier_part``: ``params`` with tier m replaced by ``part``."""
+    lo, hi = plan.tier_bounds(m)
+    out = dict(params)
+    out["units"] = _update_units(params["units"], part["units"], lo, hi)
+    for name in ("frontend", "head"):
+        if name in part:
+            out[name] = part[name]
+    return out
 
 
 # --------------------------------------------------------------------------- #
@@ -346,6 +386,15 @@ def synchronize(
     Specializing step functions instead of branching in-graph is the
     production path (see EXPERIMENTS.md sect. Perf).
 
+    Each tier is sliced out of the tree at its first level of the round
+    and written back in place at its last (``_put_tier``): a stacked unit
+    leaf is updated over the tier's unit range, so no other tier is
+    copied, and a tier with no level this round is neither read nor
+    written.  One write per tier, not per level, leaves XLA free to fold
+    a level into the next (the all-client mean of a broadcast mean).  The
+    result is bit-identical to splitting the tree with ``tier_subtrees``
+    and merging it with ``combine_tiers``.
+
     ``compress_fn`` (leaf → leaf, e.g. a vmapped ``Compressor.transform``)
     models the lossy fed-server wire of DESIGN.md §9: it is applied to the
     uploaded replicas immediately before the *fed-server* mean of tiers
@@ -381,13 +430,18 @@ def synchronize(
     if guard is not None:
         health, params = guard_health(params, plan.num_clients, guard)
         mask = health if mask is None else mask.astype(jnp.float32) * health
-    parts = tier_subtrees(params, plan)
     if fed_round is not None and not isinstance(fed_round, (tuple, list)):
         fed_round = (bool(fed_round),) * plan.M
-    out_parts: List[Params] = []
-    for m, part in enumerate(parts):
+    out = params
+    for m in range(plan.M):
         levels = plan.levels(m)
-        for li, (groups, interval) in enumerate(levels):
+        # levels this round: fed_round[m] False skips tier m's fed-server level
+        run = [
+            li for li, (_, interval) in enumerate(levels)
+            if interval <= 1 or fed_round is None or fed_round[m]
+        ]
+        for li in run:
+            groups, interval = levels[li]
             # the fed-server level is the last one of a non-top tier; it is
             # a priced wire only when several entities actually exchange.
             fed = (
@@ -409,16 +463,16 @@ def synchronize(
                 return _group_mean(p, groups)
 
             with obs.scope(obs.sync_level(m, li, len(levels))):
-                if interval <= 1:
-                    part = level_mean(part)
-                elif fed_round is None:
+                if li == run[0]:
+                    part = _tier_part(out, plan, m)
+                if interval > 1 and fed_round is None:
                     do = (step + 1) % interval == 0
                     part = lax.cond(do, level_mean, lambda p: p, part)
-                elif fed_round[m]:
+                else:
                     part = level_mean(part)
-                # fed_round[m] is False -> skip tier m's fed-server level
-        out_parts.append(part)
-    return combine_tiers(out_parts, params)
+                if li == run[-1]:
+                    out = _put_tier(out, part, plan, m)
+    return out
 
 
 # --------------------------------------------------------------------------- #

@@ -1,10 +1,24 @@
 """TierPlan + synchronize: the HSFL aggregation schedule (Eqs. 3-4)."""
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
-from repro.core.tiers import TierPlan, default_plan, synchronize, tier_subtrees, combine_tiers
+from repro.core.tiers import (
+    GuardSpec,
+    TierPlan,
+    _group_mean,
+    _group_mean_masked,
+    combine_tiers,
+    default_plan,
+    guard_health,
+    synchronize,
+    tier_subtrees,
+)
 
 
 def _params(key, N, U, d=4):
@@ -238,3 +252,198 @@ def test_round_specialization_matches_dynamic(step):
     for d_leaf, s_leaf in zip(jax.tree.leaves(dyn), jax.tree.leaves(spec)):
         np.testing.assert_allclose(np.asarray(d_leaf), np.asarray(s_leaf),
                                    rtol=0, atol=0)
+
+
+# --------------------------------------------------------------------------- #
+# in-place tier sync == the split -> levels -> concatenate formulation
+# --------------------------------------------------------------------------- #
+
+
+def _split_sync(params, plan, step, *, fed_round=None, compress_fn=None,
+                mask=None, guard=None):
+    """``synchronize`` as a split into per-tier copies, the levels on each,
+    and ``combine_tiers`` back: the formulation the in-place write-back
+    must reproduce bit for bit."""
+    if guard is not None:
+        health, params = guard_health(params, plan.num_clients, guard)
+        mask = health if mask is None else mask * health
+    if fed_round is not None and not isinstance(fed_round, (tuple, list)):
+        fed_round = (bool(fed_round),) * plan.M
+    out_parts = []
+    for m, part in enumerate(tier_subtrees(params, plan)):
+        levels = plan.levels(m)
+        for li, (groups, interval) in enumerate(levels):
+            fed = (compress_fn is not None and m < plan.M - 1
+                   and li == len(levels) - 1 and plan.entities[m] > 1)
+
+            def level_mean(p, groups=groups, fed=fed):
+                original = p
+                if fed:
+                    p = jax.tree.map(compress_fn, p)
+                if mask is not None:
+                    return _group_mean_masked(p, groups, mask, keep=original)
+                return _group_mean(p, groups)
+
+            if interval <= 1:
+                part = level_mean(part)
+            elif fed_round is None:
+                part = lax.cond((step + 1) % interval == 0, level_mean,
+                                lambda p: p, part)
+            elif fed_round[m]:
+                part = level_mean(part)
+        out_parts.append(part)
+    return combine_tiers(out_parts, params)
+
+
+def _unit_tree(key, kind, N, U, d=4):
+    """Client-stacked params whose units are one stack, an enc/dec pair of
+    stacks (4 enc units, then the decoder's) or a per-unit list."""
+    ks = jax.random.split(key, 5)
+    leaves = lambda k, n: {"w": jax.random.normal(k, (N, n, d, d)),
+                           "b": jax.random.normal(jax.random.fold_in(k, 1), (N, n, d))}
+    if kind == "stacked":
+        units = leaves(ks[1], U)
+    elif kind == "encdec":
+        units = {"enc": leaves(ks[1], 4), "dec": leaves(ks[2], U - 4)}
+    else:
+        units = [{"w": jax.random.normal(jax.random.fold_in(ks[3], u), (N, d, d))}
+                 for u in range(U)]
+    return {"frontend": {"embed": jax.random.normal(ks[0], (N, 8, d))},
+            "units": units,
+            "head": {"norm": jax.random.normal(ks[4], (N, d))}}
+
+
+# fed_round=None runs the in-graph cond at these steps; the others are the
+# sync patterns fed_round(intervals (8, 4, 1), r) dispatches
+ROUNDS = {"dynamic": None, "local": (False, False, True),
+          "fed_FTT": (False, True, True), "fed_TTT": (True, True, True)}
+
+
+@pytest.mark.parametrize("variant", ["plain", "masked", "compress", "guard"])
+@pytest.mark.parametrize("round_", list(ROUNDS))
+@pytest.mark.parametrize("kind", ["stacked", "encdec", "list"])
+def test_in_place_sync_is_bit_identical_to_split_and_concatenate(kind, round_, variant):
+    N, U = 8, 10
+    params = _unit_tree(jax.random.PRNGKey(21), kind, N, U)
+    # tier 2 spans the enc/dec boundary; tier 3 holds decoder units only
+    plan = default_plan(U, N, cuts=(3, 6), intervals=(8, 4, 1),
+                        entities=(N, 4, 1))
+    kw = {}
+    if variant == "masked":
+        # clients 0 and 1 form tier 2's first entity: a zero-participant group
+        kw["mask"] = jnp.ones((N,), jnp.float32).at[0].set(0.0).at[1].set(0.0).at[5].set(0.0)
+    elif variant == "compress":
+        kw["compress_fn"] = _lossy
+    elif variant == "guard":
+        kw["guard"] = GuardSpec()
+        bad = jax.tree.leaves(params["units"])[0]
+        params = jax.tree.map(lambda x: x.at[5].set(jnp.inf) if x is bad else x, params)
+    fed = ROUNDS[round_]
+    in_place = jax.jit(lambda p, s: synchronize(p, plan, s, fed_round=fed, **kw))
+    split = jax.jit(lambda p, s: _split_sync(p, plan, s, fed_round=fed, **kw))
+    for step in ([0, 3, 7] if fed is None else [7]):
+        got = in_place(params, jnp.int32(step))
+        want = split(params, jnp.int32(step))
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # teeth: the round really moved the params
+    assert any(not np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(params)))
+
+
+def test_in_place_sync_leaves_an_unsynced_tier_untouched():
+    """A local round reads and writes no unit of tier 1, which has no level
+    in it: its units come back as the very input values."""
+    N, U = 8, 10
+    params = _unit_tree(jax.random.PRNGKey(22), "stacked", N, U)
+    plan = default_plan(U, N, cuts=(3, 6), intervals=(8, 4, 1),
+                        entities=(N, 4, 1))
+    out = synchronize(params, plan, jnp.int32(0), fed_round=(False, False, True))
+    for a, b in zip(jax.tree.leaves(out["units"]), jax.tree.leaves(params["units"])):
+        np.testing.assert_array_equal(np.asarray(a)[:, :3], np.asarray(b)[:, :3])
+        assert not np.array_equal(np.asarray(a)[:, 3:], np.asarray(b)[:, 3:])
+    assert out["frontend"]["embed"] is params["frontend"]["embed"]
+
+
+# --------------------------------------------------------------------------- #
+# the lowered Engine-A round: no concatenate rebuilds a unit stack
+# --------------------------------------------------------------------------- #
+
+RESULT = re.compile(r"->\s*tensor<([0-9x]+)x[a-z0-9]+>")
+
+
+def _lowered_round(make, fed):
+    from repro.core import build_train_step_a, init_state_a
+    from repro.optim import sgd
+
+    model, plan, batch = make()
+    opt = sgd(1e-2)
+    state = jax.eval_shape(lambda: init_state_a(model, plan, opt, jax.random.PRNGKey(0)))
+    step = build_train_step_a(model, plan, opt, fed_round=fed)
+    return jax.jit(step).lower(state, batch).as_text(), state, plan
+
+
+def _lm_round_parts():
+    from repro.configs import get_reduced
+    from repro.configs.shapes import concrete_inputs
+    from repro.models.model import SplittableModel
+
+    N = 4
+    spec = dataclasses.replace(get_reduced("smollm-135m"), num_layers=5)
+    plan = default_plan(spec.n_units, N, cuts=(1, 3), intervals=(8, 4, 1),
+                        entities=(N, 2, 1))
+    batch = concrete_inputs(spec, N * 2, 8, jax.random.PRNGKey(1))
+    batch = {k: v.reshape(N, 2, *v.shape[1:]) for k, v in batch.items()}
+    return SplittableModel(spec), plan, batch
+
+
+def _vgg_round_parts():
+    from repro.configs import get_reduced
+    from repro.models.vgg import build_model
+
+    N = 4
+    spec = get_reduced("vgg16-cifar10")
+    plan = default_plan(spec.n_units, N, cuts=(1, 3), intervals=(8, 4, 1),
+                        entities=(N, 2, 1))
+    s = spec.image_size
+    batch = {"images": jnp.zeros((N, 2, s, s, spec.in_channels), jnp.float32),
+             "labels": jnp.zeros((N, 2), jnp.int32)}
+    return build_model(spec), plan, batch
+
+
+def _results(text, op, shapes):
+    """Result shapes of ``op`` in lowered StableHLO that are among ``shapes``."""
+    out = []
+    for line in text.splitlines():
+        if f"stablehlo.{op}" in line:
+            m = RESULT.search(line)
+            dims = tuple(int(x) for x in m.group(1).split("x")) if m else ()
+            if dims in shapes:
+                out.append(dims)
+    return out
+
+
+@pytest.mark.parametrize("round_", ["local", "fed_FTT", "fed_TTT"])
+def test_lm_round_rebuilds_no_unit_stack_by_concatenate(round_):
+    text, state, plan = _lowered_round(_lm_round_parts, ROUNDS[round_])
+    stacks = {tuple(x.shape) for x in jax.tree.leaves(state.params["units"])}
+    assert all(s[:2] == (plan.num_clients, plan.n_units) for s in stacks)
+    assert _results(text, "concatenate", stacks) == []
+    # the syncs wrote back in place instead
+    assert _results(text, "dynamic_update_slice", stacks)
+    # teeth: the detector sees the split-and-concatenate merge
+    merged = jax.jit(lambda p: combine_tiers(tier_subtrees(p, plan), p)).lower(
+        state.params).as_text()
+    assert _results(merged, "concatenate", stacks)
+
+
+@pytest.mark.parametrize("round_", ["local", "fed_TTT"])
+def test_vgg_round_keeps_list_units(round_):
+    """Per-unit list leaves are swapped in the list: the VGG round neither
+    concatenates nor updates a slice of any client-stacked parameter."""
+    text, state, plan = _lowered_round(_vgg_round_parts, ROUNDS[round_])
+    assert isinstance(state.params["units"], list)
+    shapes = {tuple(x.shape) for x in jax.tree.leaves(state.params)}
+    for op in ("concatenate", "dynamic_update_slice"):
+        assert _results(text, op, shapes) == [], op
