@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Smoke run of the main path on a TPU, through the normal entry points.
 
-    python chip_smoke.py                # one chip: the three phases below
+    python chip_smoke.py                # one chip: the four phases below
     python chip_smoke.py --four-chips   # a four-chip host: sharded Engine A
 
 One chip, one process, one compilation cache, published widths:
@@ -14,6 +14,10 @@ One chip, one process, one compilation cache, published widths:
 3. ``decode``: SmolLM-135M through the jitted ``decode_step`` that
    ``launch.serve`` uses, batch 8, cache 2048; the decoded logits are
    checked against the teacher-forced ``forward`` of the same tokens.
+4. ``grouped_experts``: the DeepSeek-V3 block's expert products (the
+   megablox grouped matmuls, under the client vmap as Engine A calls
+   them) against a dense per-expert sum at small shapes, forward and
+   gradients.
 
 ``--four-chips`` runs VGG-16 with 20 clients, 5 edges, sharded over
 ``data=4`` (groups of 4 straddle shards of 5: the one-hot einsum + psum
@@ -43,6 +47,10 @@ PARAM_RTOL, PARAM_ATOL = 2e-5, 2e-6
 # decode-vs-forward: both run the chip's default f32 matmul precision,
 # which rounds matmul inputs to bf16, along different reduction orders
 DECODE_REL_TOL = 5e-2
+# grouped-vs-dense experts: on the chip the grouped products take bf16
+# operands (f32 accumulation), the dense sum f32 at highest; relative
+# error of each output's norm, a few bf16 roundings deep
+EXPERTS_REL_TOL = 2e-2
 
 _COMPILE_EVENTS = (
     "/jax/core/compile/jaxpr_trace_duration",
@@ -228,6 +236,64 @@ def phase_decode(spec=None, batch=8, cache_len=2048, prefill=4, gen=4, seed=0) -
     }
 
 
+def phase_grouped_experts(clients=2, tokens=256, top_k=6, held=8, d=256, f=256,
+                          seed=0) -> dict:
+    """``layers.grouped_experts`` under a vmap over clients, each with its
+    own experts, against a dense per-expert sum: each (token, choice)
+    pair's output, and the gradients of a weighted sum of them by the
+    tokens and by every expert weight.  About a quarter of the pairs are
+    routed to experts held elsewhere and must give zero."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.models.layers import grouped_experts
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    xt = jax.random.normal(ks[0], (clients, tokens, d))
+    gid = jnp.minimum(jax.random.randint(ks[1], (clients, tokens, top_k), 0, held + held // 3),
+                      held)  # group ``held``: an expert held elsewhere
+    w1, w3 = (jax.random.normal(k, (clients, held, d, f)) / np.sqrt(d) for k in ks[2:4])
+    w2 = jax.random.normal(ks[4], (clients, held, f, d)) / np.sqrt(f)
+    cot = jax.random.normal(ks[5], (clients, tokens, top_k, d))
+
+    def dense(xt, gid, w1, w3, w2):
+        with jax.default_matmul_precision("highest"):
+            h1 = jnp.einsum("td,gdf->tgf", xt, w1)
+            h3 = jnp.einsum("td,gdf->tgf", xt, w3)
+            y = jnp.einsum("tgf,gfd->tgd", jax.nn.silu(h1) * h3, w2)
+            return jnp.einsum("tkg,tgd->tkd", jax.nn.one_hot(gid, held, dtype=y.dtype), y)
+
+    def outputs(fn):
+        def loss(xt, gid, w1, w3, w2):
+            ye = jax.vmap(fn)(xt, gid, w1, w3, w2)
+            return jnp.sum(ye * cot), ye
+
+        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 2, 3, 4), has_aux=True))
+        (_, ye), grads = step(xt, gid, w1, w3, w2)
+        return dict(zip(("out", "d_x", "d_w1", "d_w3", "d_w2"), (ye, *grads)))
+
+    with CompileClock() as clock:
+        got = outputs(grouped_experts)
+        jax.block_until_ready(got)
+    want = outputs(dense)
+    rel = {}
+    for name, a in got.items():
+        a, b = np.asarray(a, np.float64), np.asarray(want[name], np.float64)
+        _finite(f"grouped_experts {name}", a)
+        rel[name] = float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+    here = np.asarray(gid) < held
+    if np.any(np.asarray(got["out"])[~here] != 0):
+        raise AssertionError("grouped_experts: a pair held elsewhere gave output")
+    if max(rel.values()) > EXPERTS_REL_TOL:
+        raise AssertionError(f"grouped_experts differs from the dense sum: {rel}")
+    return {
+        "phase": "grouped_experts", "device": _device_of(got["out"]),
+        "clients": clients, "pairs_held": int(here.sum()), "pairs": int(here.size),
+        "compile_s": clock.seconds, "rel_diff": rel, "peak_bytes_in_use": _peak_bytes(),
+    }
+
+
 def phase_sharded_vgg(clients=20, edges=5, batch=16, rounds=5, data=4, seed=0) -> dict:
     """The same VGG run sharded over ``data`` devices and on one device.
 
@@ -315,6 +381,7 @@ def main(argv=None) -> int:
             lambda: phase_train_vgg(seed=args.seed),
             lambda: phase_train_lm(seed=args.seed),
             lambda: phase_decode(seed=args.seed),
+            lambda: phase_grouped_experts(seed=args.seed),
         ]
     for phase in phases:
         print(json.dumps(phase()), flush=True)

@@ -15,6 +15,8 @@ _MODULES: Dict[str, str] = {
     "phi3.5-moe-42b-a6.6b": "phi3_5_moe_42b_a6_6b",
     "mamba2-1.3b": "mamba2_1_3b",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
+    "moonlight-16b-a3b-ep8": "moonlight_16b_a3b_ep8",
     "vgg16-cifar10": "vgg16_cifar10",
 }
 

@@ -5,7 +5,7 @@ from ..models.spec import ModelSpec
 SPEC = ModelSpec(
     name="smollm-135m", family="dense", num_layers=30, d_model=576,
     num_heads=9, num_kv_heads=3, d_ff=1536, vocab_size=49152,
-    tie_embeddings=True,
+    tie_embeddings=True, norm_eps=1e-5,
     source="hf:HuggingFaceTB/SmolLM-135M",
 )
 

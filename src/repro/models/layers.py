@@ -14,7 +14,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .spec import ModelSpec
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm, tgmm as megablox_tgmm
+
+from .. import obs
+from .spec import ModelSpec, pad_to
 
 Params = Dict[str, Any]
 
@@ -457,6 +460,311 @@ def moe(params: Params, x: jax.Array, spec: ModelSpec,
     )
     aux = E * jnp.mean(jnp.sum(me * ce, axis=-1))
     return out.reshape(B, S, d), aux
+
+
+# --------------------------------------------------------------------------- #
+# multi-head latent attention (DeepSeek-V3 MLA, no query latent)
+# --------------------------------------------------------------------------- #
+
+
+def rope_interleaved(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding on adjacent pairs ``(x[2i], x[2i+1])``, DeepSeek's
+    pairing.  x: [..., S, H, r]; positions: [S].  The rotated pairs come
+    out de-interleaved (first members, then second members), as DeepSeek's
+    code leaves them: queries and keys take the same permutation, so their
+    dot products are those of the pairs rotated in place."""
+    half = x.shape[-1] // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ang = positions[..., :, None].astype(jnp.float32) * freqs  # [S, half]
+    cos = jnp.cos(ang)[..., :, None, :]
+    sin = jnp.sin(ang)[..., :, None, :]
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def init_mla(key, spec: ModelSpec) -> Params:
+    m, d, h = spec.mla, spec.d_model, spec.num_heads
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": _dense_init(ks[0], (d, h * m.qk_dim), spec.pdtype),
+        "wkva": _dense_init(ks[1], (d, m.kv_rank + m.rope_dim), spec.pdtype),
+        "kv_norm": jnp.zeros((m.kv_rank,), spec.pdtype),
+        "wkvb": _dense_init(ks[2], (m.kv_rank, h * (m.nope_dim + m.v_dim)), spec.pdtype),
+        "wo": _dense_init(ks[3], (h * m.v_dim, d), spec.pdtype),
+        "norm": jnp.zeros((d,), spec.pdtype),
+    }
+
+
+def mla(params: Params, x: jax.Array, spec: ModelSpec, positions: jax.Array,
+        cache: Optional[Params] = None) -> Tuple[jax.Array, Optional[Params]]:
+    """Causal MLA sub-layer (pre-norm and residual by the caller).
+
+    x [B, S, d]; positions [S].  The latent ``c`` (after its RMSNorm) and
+    the rotated shared key ``k_pe`` are what a position keeps: with a
+    ``cache`` ({"c": [B, C, rank], "k_pe": [B, C, rope], "positions": [C],
+    "index"}) one token is written at ``index`` and the keys and values of
+    every cached position are rebuilt from the latents."""
+    m, h = spec.mla, spec.num_heads
+    B, S, _ = x.shape
+    with obs.scope(obs.MLA):
+        q = (x @ params["wq"]).reshape(B, S, h, m.qk_dim)
+        q_nope = q[..., : m.nope_dim]
+        q_pe = rope_interleaved(q[..., m.nope_dim:], positions, spec.rope_theta)
+        kva = x @ params["wkva"]
+        c = rms_norm(kva[..., : m.kv_rank], params["kv_norm"], spec.norm_eps)
+        k_pe = rope_interleaved(kva[..., None, m.kv_rank:], positions, spec.rope_theta)[:, :, 0]
+        k_pos = positions
+        new_cache = None
+        if cache is not None:
+            idx = cache["index"]
+            c = cache["c"].at[:, idx].set(c[:, 0])
+            k_pe = cache["k_pe"].at[:, idx].set(k_pe[:, 0])
+            k_pos = cache["positions"].at[idx].set(idx)
+            new_cache = {"c": c, "k_pe": k_pe, "positions": k_pos, "index": idx + 1}
+        kv = (c @ params["wkvb"]).reshape(B, c.shape[1], h, m.nope_dim + m.v_dim)
+        k_nope, v = kv[..., : m.nope_dim], kv[..., m.nope_dim:]
+        s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+             + jnp.einsum("bqhr,bkr->bhqk", q_pe, k_pe)).astype(jnp.float32)
+        s = s / math.sqrt(m.qk_dim) + _mask_bias(positions, k_pos, True, 0, 0)
+        w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        o = jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(B, S, h * m.v_dim)
+        return o @ params["wo"], new_cache
+
+
+def init_mla_cache(spec: ModelSpec, batch: int, cache_len: int) -> Params:
+    m = spec.mla
+    return {
+        "c": jnp.zeros((batch, cache_len, m.kv_rank), spec.cdtype),
+        "k_pe": jnp.zeros((batch, cache_len, m.rope_dim), spec.cdtype),
+        "positions": jnp.full((cache_len,), -1, jnp.int32),
+        "index": jnp.zeros((), jnp.int32),
+    }
+
+
+# --------------------------------------------------------------------------- #
+# sigmoid-routed experts beside shared experts (DeepSeek-V3), no dropped tokens
+# --------------------------------------------------------------------------- #
+
+
+# std of the selection bias (e_score_correction_bias), drawn from the seed
+# and held fixed: it moves which experts are chosen, never a weight
+SELECTION_BIAS_STD = 0.01
+
+
+def init_routed_moe(key, spec: ModelSpec) -> Params:
+    """Router over all ``num_experts``, its fixed selection bias, the held
+    experts' SwiGLUs stacked [n_held, ...] and the shared experts.  Expert
+    e's weights come from the e-th of ``num_experts`` keys, so every share
+    of the experts holds the same weights as the whole layer."""
+    r, d, f = spec.routed, spec.d_model, spec.routed.d_expert
+    ks = jax.random.split(key, 4)
+
+    def expert(k):
+        k1, k2, k3 = jax.random.split(k, 3)
+        return (_dense_init(k1, (d, f), spec.pdtype), _dense_init(k3, (d, f), spec.pdtype),
+                _dense_init(k2, (f, d), spec.pdtype))
+
+    keys = jax.random.split(ks[1], r.num_experts)[r.first_held: r.first_held + r.n_held]
+    w1, w3, w2 = jax.vmap(expert)(keys)
+    p = {
+        "router": _dense_init(ks[0], (d, r.num_experts), spec.pdtype),
+        "bias": (jax.random.normal(ks[3], (r.num_experts,)) * SELECTION_BIAS_STD).astype(spec.pdtype),
+        "w1": w1, "w3": w3, "w2": w2,
+        "norm": jnp.zeros((d,), spec.pdtype),
+    }
+    if r.num_shared:
+        shared = init_mlp(ks[2], spec, d_ff=r.num_shared * f)
+        p["shared"] = {k: shared[k] for k in ("w1", "w2", "w3")}
+    return p
+
+
+def routed_moe(params: Params, x: jax.Array, spec: ModelSpec) -> jax.Array:
+    """The expert sub-layer (pre-norm and residual by the caller).
+
+    Scores are ``sigmoid(x W_g)`` over every expert, in float32 at the
+    highest matmul precision.  The top ``top_k`` of scores plus the fixed
+    bias are chosen (the bias gets no gradient); their unbiased scores,
+    normalised to sum 1 (``norm_topk``) and times ``routed_scale``, weight
+    the chosen experts' outputs.  Of those, only the held experts' part is
+    computed here, for every pair routed to them: no token is dropped.  The
+    shared experts run on every token."""
+    r = spec.routed
+    B, S, d = x.shape
+    xt = x.reshape(B * S, d)
+    with obs.scope(obs.MOE_ROUTE):
+        logits = jnp.dot(xt.astype(jnp.float32), params["router"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, idx = lax.top_k(scores + lax.stop_gradient(params["bias"].astype(jnp.float32)),
+                           r.top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if r.norm_topk:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w * r.routed_scale
+        local = idx - r.first_held
+        here = (local >= 0) & (local < r.n_held)
+        gid = jnp.where(here, local, r.n_held)
+    ye = grouped_experts(xt, gid, params["w1"], params["w3"], params["w2"])
+    with obs.scope(obs.MOE_ROUTE):
+        out = jnp.einsum("tkd,tk->td", ye, jnp.where(here, w, 0.0).astype(ye.dtype))
+    if "shared" in params:
+        with obs.scope(obs.MOE_SHARED):
+            out = out + mlp(params["shared"], xt)
+    return out.reshape(B, S, d)
+
+
+def _operand_dtype(dtype):
+    """On the TPU a float32 matmul at the default precision rounds its
+    operands to bfloat16 and accumulates in float32, so the megablox kernels
+    are handed them so rounded; elsewhere they are interpreted in the
+    operands' own dtype.  That CPU path exists for the tests, which hold
+    the program to the float32 reference at tight tolerances; it is not
+    the chip's arithmetic, which ``chip_smoke.py``'s ``grouped_experts``
+    phase checks against a dense per-expert sum on the TPU."""
+    return jnp.bfloat16 if jax.default_backend() == "tpu" else dtype
+
+
+def _megablox_args(m: int, k: int, n: int):
+    """Row padding, tiling and whether to interpret the megablox kernels."""
+    tm = min(512, pad_to(m, 128))
+    return pad_to(m, tm) - m, (tm, min(512, k), min(512, n)), jax.default_backend() != "tpu"
+
+
+def _gmm(x: jax.Array, w: jax.Array, sizes: jax.Array, transpose_rhs: bool = False) -> jax.Array:
+    """Rows of group g of ``x`` [m, k] times ``w[g]`` ([G, k, n], or [G, n, k]
+    with ``transpose_rhs``): the megablox grouped matmul, which visits the
+    tiles of the groups alone and leaves the rows past the last group
+    unwritten (zeroed here).  Float32 out."""
+    m, k = x.shape
+    n = w.shape[1] if transpose_rhs else w.shape[2]
+    pad, tiling, interpret = _megablox_args(m, k, n)
+    out = megablox_gmm(jnp.pad(x, ((0, pad), (0, 0))).astype(_operand_dtype(x.dtype)),
+                       w.astype(_operand_dtype(w.dtype)), sizes, preferred_element_type=jnp.float32,
+                       tiling=tiling, transpose_rhs=transpose_rhs, interpret=interpret)
+    return jnp.where((jnp.arange(m) < jnp.sum(sizes))[:, None], out[:m], 0.0)
+
+
+def _wgrad(x: jax.Array, dy: jax.Array, sizes: jax.Array) -> jax.Array:
+    """Per group g, ``x[rows of g].T @ dy[rows of g]``: [G, in, out], float32."""
+    m = x.shape[0]
+    pad, tiling, interpret = _megablox_args(m, x.shape[1], dy.shape[1])
+    rows = ((0, pad), (0, 0))
+    return megablox_tgmm(jnp.pad(x, rows).astype(_operand_dtype(x.dtype)).T,
+                         jnp.pad(dy, rows).astype(_operand_dtype(dy.dtype)), sizes,
+                         preferred_element_type=jnp.float32, tiling=tiling,
+                         interpret=interpret)
+
+
+def _experts_fwd(xt, gid, w1, w3, w2):
+    """xt [T, d]; gid [T, K], each pair's group in [0, G) or G (not here);
+    w1, w3 [G, d, f], w2 [G, f, d].  The pairs are sorted by group, so the
+    grouped products run over the routed rows alone (the rows of pairs
+    held elsewhere sit past the last group).  Returns each pair's output
+    [T, K, d] (zero for pairs not here) and what the backward pass keeps."""
+    T, K = gid.shape
+    G = w1.shape[0]
+    with obs.scope(obs.MOE_ROUTE):
+        flat = gid.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * K, dtype=order.dtype))
+        sizes = jnp.sum(flat[:, None] == jnp.arange(G)[None, :], axis=0, dtype=jnp.int32)
+        xs = xt.astype(_operand_dtype(xt.dtype))[order // K]
+    with obs.scope(obs.MOE_EXPERTS):
+        h1 = _gmm(xs, w1, sizes)
+        h3 = _gmm(xs, w3, sizes)
+        y = _gmm(jax.nn.silu(h1) * h3, w2, sizes)
+    with obs.scope(obs.MOE_ROUTE):
+        y = y[inv].astype(xt.dtype)
+    return y.reshape(T, K, -1), (xs, h1, h3, order, inv, sizes)
+
+
+def _experts_bwd(res, w1, w3, w2, dye):
+    xs, h1, h3, order, inv, sizes = res
+    T, K, d = dye.shape
+    with obs.scope(obs.MOE_ROUTE):
+        dy = dye.reshape(T * K, d)[order]
+    with obs.scope(obs.MOE_EXPERTS):
+        sig = jax.nn.sigmoid(h1)
+        a = h1 * sig
+        dhh = _gmm(dy, w2, sizes, transpose_rhs=True)
+        dw2 = _wgrad(a * h3, dy, sizes)
+        dh3 = dhh * a
+        dh1 = dhh * h3 * sig * (1.0 + h1 * (1.0 - sig))
+        dxs = _gmm(dh1, w1, sizes, transpose_rhs=True) + _gmm(dh3, w3, sizes, transpose_rhs=True)
+        dw1 = _wgrad(xs, dh1, sizes)
+        dw3 = _wgrad(xs, dh3, sizes)
+    with obs.scope(obs.MOE_ROUTE):
+        dxt = jnp.sum(dxs[inv].reshape(T, K, d), axis=1).astype(dye.dtype)
+    return dxt, dw1.astype(w1.dtype), dw3.astype(w3.dtype), dw2.astype(w2.dtype)
+
+
+def _fold_clients(n, batched, xs):
+    """Broadcast the unbatched of ``xs`` to ``n`` and fold the leading axis
+    into the next: [n, a, ...] -> [n * a, ...]."""
+    out = []
+    for x, b in zip(xs, batched):
+        x = x if b else jnp.broadcast_to(x[None], (n,) + x.shape)
+        out.append(x.reshape((n * x.shape[1],) + x.shape[2:]))
+    return out
+
+
+def _unfold(n, x):
+    return x.reshape((n, x.shape[0] // n) + x.shape[1:])
+
+
+# The grouped products take no batch dimension on the TPU, so a vmap over
+# clients (Engine A's) folds the clients into the groups: client i's held
+# expert g is group i * G + g, and one grouped product serves every client.
+@jax.custom_batching.custom_vmap
+def _experts_fwd_call(xt, gid, w1, w3, w2):
+    return _experts_fwd(xt, gid, w1, w3, w2)
+
+
+@_experts_fwd_call.def_vmap
+def _experts_fwd_vmap(n, in_batched, xt, gid, w1, w3, w2):
+    G = w1.shape[1] if in_batched[2] else w1.shape[0]
+    gid = gid if in_batched[1] else jnp.broadcast_to(gid[None], (n,) + gid.shape)
+    gid = jnp.where(gid < G, gid + G * jnp.arange(n, dtype=gid.dtype)[:, None, None], n * G)
+    args = _fold_clients(n, (in_batched[0], True) + tuple(in_batched[2:]), (xt, gid, w1, w3, w2))
+    ye, res = _experts_fwd(*args)
+    return (_unfold(n, ye), tuple(_unfold(n, r) for r in res)), (True, (True,) * len(res))
+
+
+@jax.custom_batching.custom_vmap
+def _experts_bwd_call(res, w1, w3, w2, dye):
+    return _experts_bwd(res, w1, w3, w2, dye)
+
+
+@_experts_bwd_call.def_vmap
+def _experts_bwd_vmap(n, in_batched, res, w1, w3, w2, dye):
+    if not all(in_batched[0]):
+        raise NotImplementedError("grouped experts: the backward pass is batched "
+                                  "only where the forward pass was")
+    res = tuple(r.reshape((-1,) + r.shape[2:]) for r in res)
+    w1, w3, w2, dye = _fold_clients(n, in_batched[1:], (w1, w3, w2, dye))
+    outs = _experts_bwd(res, w1, w3, w2, dye)
+    return tuple(_unfold(n, o) for o in outs), (True,) * 4
+
+
+@jax.custom_vjp
+def grouped_experts(xt, gid, w1, w3, w2):
+    """Each (token, choice) pair's output of its expert's SwiGLU, over the
+    held experts only (see ``_experts_fwd``)."""
+    return _experts_fwd_call(xt, gid, w1, w3, w2)[0]
+
+
+def _grouped_experts_fwd(xt, gid, w1, w3, w2):
+    ye, res = _experts_fwd_call(xt, gid, w1, w3, w2)
+    return ye, (res, w1, w3, w2)
+
+
+def _grouped_experts_bwd(saved, dye):
+    res, w1, w3, w2 = saved
+    dxt, dw1, dw3, dw2 = _experts_bwd_call(res, w1, w3, w2, dye)
+    return dxt, None, dw1, dw3, dw2
+
+
+grouped_experts.defvjp(_grouped_experts_fwd, _grouped_experts_bwd)
 
 
 # --------------------------------------------------------------------------- #

@@ -5,7 +5,9 @@ The HSFL engine only relies on:
   * ``loss_fn(params, batch)`` / ``forward(params, batch)``
   * unit stacks being stacked on axis 0 so cut ranges are slices.
 
-Families: dense | moe | ssm | hybrid | vlm | audio (enc-dec).
+Families: dense | moe | ssm | hybrid | vlm | audio (enc-dec) | mla_moe
+(DeepSeek-V3: leading dense layers in the frontend, then MLA + routed and
+shared experts per unit).
 """
 from __future__ import annotations
 
@@ -77,6 +79,8 @@ class SplittableModel:
             return {"attn": L.init_attention(ks[0], spec), "mlp": L.init_mlp(ks[1], spec)}
         if kind == "moe":
             return {"attn": L.init_attention(ks[0], spec), "moe": L.init_moe(ks[1], spec)}
+        if kind == "mla_moe":
+            return {"attn": L.init_mla(ks[0], spec), "moe": L.init_routed_moe(ks[1], spec)}
         if kind == "ssm":
             return {"mamba": L.init_mamba(ks[0], spec)}
         if kind == "hybrid":
@@ -128,6 +132,14 @@ class SplittableModel:
                 jax.random.normal(jax.random.fold_in(kf, 2), (spec.encoder_len, d))
                 * 0.02
             ).astype(spec.pdtype)
+        if spec.first_dense:
+            def dense_layer(k):
+                ka, km = jax.random.split(k)
+                return {"attn": L.init_mla(ka, spec), "mlp": L.init_mlp(km, spec)}
+
+            frontend["dense"] = jax.vmap(dense_layer)(
+                jax.random.split(jax.random.fold_in(kf, 1), spec.first_dense)
+            )
 
         if spec.family == "audio":
             ne, nd = spec.encoder_layers, spec.num_layers
@@ -141,7 +153,7 @@ class SplittableModel:
             }
         else:
             kind = {"dense": "dense", "vlm": "dense", "moe": "moe",
-                    "ssm": "ssm", "hybrid": "hybrid"}[spec.family]
+                    "ssm": "ssm", "hybrid": "hybrid", "mla_moe": "mla_moe"}[spec.family]
             units = jax.vmap(lambda k: self._init_unit(k, kind))(
                 jax.random.split(ku, spec.n_units)
             )
@@ -180,6 +192,8 @@ class SplittableModel:
                 up["mamba"], L.rms_norm(h, up["mamba"]["norm"], eps), spec
             )
             h = h + o
+        elif fam == "mla_moe":
+            h, _ = self._mla_moe_layer(up, h, positions)
         elif fam == "hybrid":
             per = spec.attn_period
             i_m = i_moe = i_mlp = 0
@@ -214,6 +228,19 @@ class SplittableModel:
         out["h"] = h
         out["aux"] = aux
         return out
+
+    def _mla_moe_layer(self, up: Params, h, positions, cache=None):
+        """One MLA layer with a routed-expert (unit) or dense (``"mlp"``,
+        the leading layers) feed-forward; returns (h, new MLA cache)."""
+        spec, eps = self.spec, self.spec.norm_eps
+        a, nc = L.mla(up["attn"], L.rms_norm(h, up["attn"]["norm"], eps), spec,
+                      positions, cache)
+        h = h + a
+        if "mlp" in up:
+            o = L.mlp(up["mlp"], L.rms_norm(h, up["mlp"]["norm"], eps))
+        else:
+            o = L.routed_moe(up["moe"], L.rms_norm(h, up["moe"]["norm"], eps), spec)
+        return h + o, nc
 
     def _apply_enc_unit(self, up: Params, henc: jax.Array) -> jax.Array:
         spec = self.spec
@@ -317,6 +344,16 @@ class SplittableModel:
             h = emb[batch["tokens"]].astype(spec.cdtype)
             return {"h": h, "enc": henc, "aux": jnp.zeros((), jnp.float32)}
         h = emb[batch["tokens"]].astype(spec.cdtype)
+        if spec.first_dense:
+            positions = jnp.arange(h.shape[1])
+
+            def layer(h, up):
+                return self._mla_moe_layer(up, h, positions)[0]
+
+            if spec.remat:
+                layer = self._remat(layer)
+            for i in range(spec.first_dense):
+                h = layer(h, jax.tree.map(lambda x: x[i], frontend["dense"]))
         return {"h": h, "aux": jnp.zeros((), jnp.float32)}
 
     def head_apply(self, params: Params, carry: Params) -> jax.Array:
@@ -368,6 +405,8 @@ class SplittableModel:
                 return {"attn": L.init_attn_cache(spec, batch, cache_len)}
             if kind == "moe":
                 return {"attn": L.init_attn_cache(spec, batch, cache_len)}
+            if kind == "mla_moe":
+                return {"attn": L.init_mla_cache(spec, batch, cache_len)}
             if kind == "ssm":
                 return {"mamba": L.init_mamba_cache(spec, batch)}
             if kind == "hybrid":
@@ -399,11 +438,14 @@ class SplittableModel:
                 *[one("dec") for _ in range(spec.num_layers)],
             )
             return caches
+        stack = lambda n, kind: jax.tree.map(
+            lambda *xs: jnp.stack(xs), *[one(kind) for _ in range(n)])
+        if spec.family == "mla_moe":
+            return {"dense": stack(spec.first_dense, "mla_moe"),
+                    "units": stack(spec.n_units, "mla_moe")}
         kind = {"dense": "dense", "vlm": "dense", "moe": "moe",
                 "ssm": "ssm", "hybrid": "hybrid"}[spec.family]
-        return jax.tree.map(
-            lambda *xs: jnp.stack(xs), *[one(kind) for _ in range(spec.n_units)]
-        )
+        return stack(spec.n_units, kind)
 
     def _decode_unit(self, up: Params, cache: Params, carry: Params, pos) -> Tuple[Params, Params]:
         spec = self.spec
@@ -422,6 +464,10 @@ class SplittableModel:
             else:
                 o = L.mlp(up["mlp"], L.rms_norm(h, up["mlp"]["norm"], eps))
             carry = dict(carry); carry["h"] = h + o
+            return carry, {"attn": nc}
+        if fam == "mla_moe":
+            h, nc = self._mla_moe_layer(up, h, pos, cache["attn"])
+            carry = dict(carry); carry["h"] = h
             return carry, {"attn": nc}
         if fam == "ssm":
             o, nc = L.mamba_block(
@@ -489,6 +535,15 @@ class SplittableModel:
         carry = {"h": h, "aux": jnp.zeros((), jnp.float32)}
         pos = pos_index[None]  # [1]
         units = params["units"]["dec"] if spec.family == "audio" else params["units"]
+        dense_caches = None
+        if spec.family == "mla_moe":
+            dense_caches = []
+            for i in range(spec.first_dense):
+                up, uc = jax.tree.map(
+                    lambda x: x[i], (params["frontend"]["dense"], caches["dense"]))
+                carry, nc = self._decode_unit(up, uc, carry, pos)
+                dense_caches.append(nc)
+            caches = caches["units"]
 
         def body(c, xs):
             up, uc = xs
@@ -496,5 +551,8 @@ class SplittableModel:
             return c2, nc
 
         carry, new_caches = lax.scan(body, carry, (units, caches), unroll=self._unroll)
+        if dense_caches is not None:
+            new_caches = {"dense": jax.tree.map(lambda *xs: jnp.stack(xs), *dense_caches),
+                          "units": new_caches}
         logits = self.head_apply(params, carry)
         return logits[:, 0], new_caches

@@ -27,6 +27,45 @@ class MoeSpec:
 
 
 @dataclass(frozen=True)
+class MlaSpec:
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query latent:
+    queries are ``x W_q``; keys and values come from a ``kv_rank`` latent
+    (RMS-normed, then expanded per head) beside one ``rope_dim`` rotary key
+    shared by every head."""
+    kv_rank: int = 512  # kv_lora_rank
+    nope_dim: int = 128  # qk_nope_head_dim
+    rope_dim: int = 64  # qk_rope_head_dim
+    v_dim: int = 128  # v_head_dim
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+
+@dataclass(frozen=True)
+class RoutedMoeSpec:
+    """Sigmoid-scored experts beside shared experts (DeepSeek-V3), routed
+    with no dropped tokens.  The router scores all ``num_experts``; a
+    device holds experts ``held = (first, last)`` (all of them when None)
+    and computes only their part of the layer."""
+    num_experts: int  # n_routed_experts: the router's width
+    top_k: int  # num_experts_per_tok
+    d_expert: int  # moe_intermediate_size
+    num_shared: int = 0  # n_shared_experts: one SwiGLU of num_shared * d_expert
+    routed_scale: float = 1.0  # routed_scaling_factor
+    norm_topk: bool = True  # norm_topk_prob
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def first_held(self) -> int:
+        return self.held[0] if self.held else 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] - self.held[0] if self.held else self.num_experts
+
+
+@dataclass(frozen=True)
 class SsmSpec:
     state_dim: int = 128
     head_dim: int = 64
@@ -38,7 +77,7 @@ class SsmSpec:
 @dataclass(frozen=True)
 class ModelSpec:
     name: str
-    family: str  # dense | moe | ssm | hybrid | vlm | audio | vgg
+    family: str  # dense | moe | ssm | hybrid | vlm | audio | mla_moe | vgg
     num_layers: int
     d_model: int
     num_heads: int
@@ -48,6 +87,11 @@ class ModelSpec:
     head_dim: int = 0  # 0 -> d_model // num_heads
     moe: Optional[MoeSpec] = None
     ssm: Optional[SsmSpec] = None
+    mla: Optional[MlaSpec] = None
+    routed: Optional[RoutedMoeSpec] = None
+    # mla_moe: the leading dense layers (MLA + SwiGLU of d_ff) live with the
+    # frontend, so they are always tier 1; the units are the expert layers.
+    first_dense: int = 0
     qk_norm: bool = False
     qkv_bias: bool = False
     tie_embeddings: bool = False
@@ -94,7 +138,7 @@ class ModelSpec:
             return self.num_layers // self.attn_period  # super-blocks
         if self.family == "audio":
             return self.encoder_layers + self.num_layers
-        return self.num_layers
+        return self.num_layers - self.first_dense
 
     @property
     def layers_per_unit(self) -> int:
@@ -142,6 +186,8 @@ class ModelSpec:
 
         if self.family in ("dense", "vlm"):
             return attn_params() + mlp_params(ff)
+        if self.family == "mla_moe":
+            return self.mla_param_count() + self.routed_param_count()
         if self.family == "moe":
             return attn_params() + moe_params(self.moe)
         if self.family == "ssm":
@@ -163,8 +209,24 @@ class ModelSpec:
             return dec if unit >= self.encoder_layers else enc
         raise ValueError(self.family)
 
+    def mla_param_count(self) -> int:
+        """One MLA sub-layer with its pre-norm."""
+        m, d, h = self.mla, self.d_model, self.num_heads
+        return (d * h * m.qk_dim + d * (m.kv_rank + m.rope_dim) + m.kv_rank
+                + m.kv_rank * h * (m.nope_dim + m.v_dim) + h * m.v_dim * d + d)
+
+    def routed_param_count(self) -> int:
+        """One expert sub-layer as held here: the router and its bias, the
+        held experts, the shared experts and the pre-norm."""
+        r, d = self.routed, self.d_model
+        return (d * r.num_experts + r.num_experts + r.n_held * 3 * d * r.d_expert
+                + 3 * d * r.num_shared * r.d_expert + d)
+
     def frontend_param_count(self) -> int:
-        return self.padded_vocab * self.d_model
+        p = self.padded_vocab * self.d_model
+        if self.first_dense:
+            p += self.first_dense * (self.mla_param_count() + 3 * self.d_model * self.d_ff + self.d_model)
+        return p
 
     def head_param_count(self) -> int:
         p = self.d_model
@@ -180,7 +242,14 @@ class ModelSpec:
         )
 
     def active_param_count(self) -> int:
-        """Parameters active per token (MoE top-k instead of all experts)."""
+        """Parameters active per token (MoE top-k instead of all experts).
+
+        mla_moe counts what this device holds: of its held experts, the
+        expected ``top_k * n_held / num_experts`` a token is routed to."""
+        if self.family == "mla_moe":
+            r = self.routed
+            idle = (r.n_held - r.top_k * r.n_held / r.num_experts) * 3 * self.d_model * r.d_expert
+            return int(round(self.total_param_count() - self.n_units * idle))
         if self.moe is None:
             return self.total_param_count()
         ms = self.moe
@@ -224,6 +293,15 @@ class ModelSpec:
 
         if self.family in ("dense", "vlm"):
             return attn_flops(ctx) + mlp_flops(ff)
+        if self.family == "mla_moe":
+            m, r = self.mla, self.routed
+            proj = 2.0 * T * (d * h * m.qk_dim + d * (m.kv_rank + m.rope_dim)
+                              + m.kv_rank * h * (m.nope_dim + m.v_dim) + h * m.v_dim * d)
+            scores = 2.0 * batch * seq * ctx * h * (m.qk_dim + m.v_dim)
+            held_pairs = T * r.top_k * r.n_held / r.num_experts  # expected
+            return (proj + scores + 2.0 * T * d * r.num_experts
+                    + held_pairs * 2.0 * 3 * d * r.d_expert
+                    + mlp_flops(r.num_shared * r.d_expert))
         if self.family == "moe":
             return attn_flops(ctx) + moe_flops(self.moe)
         if self.family == "ssm":

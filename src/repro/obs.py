@@ -12,6 +12,15 @@ phase in its ``tf_op`` path:
   level of tier m (counted from 1): its last level is the fed-server level,
   any level before it an entity level.
 
+The ``mla_moe`` family names its sub-layers inside ``hsfl.grad`` (a phase's
+time is charged to the innermost name, and ``hsfl.grad`` takes every phase
+nested in its name, so it keeps its meaning):
+
+* ``hsfl.grad.mla`` — latent attention;
+* ``hsfl.grad.moe.route`` — the gate, the selection, dispatch and combine;
+* ``hsfl.grad.moe.experts`` — the grouped products over the held experts;
+* ``hsfl.grad.moe.shared`` — the shared experts.
+
 The host span ``loader`` times ``FederatedLoader.next_round``.  It is
 stamped with ``time.time_ns()`` (``CLOCK_REALTIME``, the clock of the
 profiler's host timestamps) into a bounded buffer that ``host_spans()``
@@ -28,6 +37,10 @@ import jax
 
 GRAD = "hsfl.grad"
 OPT = "hsfl.opt"
+MLA = "hsfl.grad.mla"
+MOE_ROUTE = "hsfl.grad.moe.route"
+MOE_EXPERTS = "hsfl.grad.moe.experts"
+MOE_SHARED = "hsfl.grad.moe.shared"
 LOADER = "loader"
 MAX_HOST_SPANS = 4096
 

@@ -1,7 +1,8 @@
 """``chip_smoke.py`` on the CPU: its phases at small sizes, and its refusals.
 
 The phases are the functions the chip run calls, seeded; here they run
-the full-width VGG-16 at 2 clients and the REDUCED SmolLM.  ``main()``
+the full-width VGG-16 at 2 clients, the REDUCED SmolLM and the grouped
+experts at a small size.  ``main()``
 itself insists on a TPU, which this machine does not have.
 """
 import os
@@ -51,6 +52,14 @@ def test_phase_decode_reduced():
     assert r["device"] == "cpu"
     assert r["logits_shape"] == [2, spec.padded_vocab]
     assert r["forward_rel_diff"] < 1e-4
+
+
+def test_phase_grouped_experts_small():
+    r = chip_smoke.phase_grouped_experts(tokens=32, top_k=3, held=4, d=128, f=128, seed=1)
+    assert r["device"] == "cpu"
+    assert 0 < r["pairs_held"] < r["pairs"]
+    # off the chip the kernels are interpreted on float32 operands
+    assert max(r["rel_diff"].values()) < 1e-5
 
 
 def test_main_refuses_a_machine_without_tpu(capsys):

@@ -25,6 +25,9 @@ from repro.optim import sgd
         ("mamba2-1.3b", (1, 2), (2, 2, 1)),
         ("granite-moe-1b-a400m", (1, 2), (2, 3, 1)),  # MoE: dispatch+aux path
         ("jamba-1.5-large-398b", (1, 1), (4, 2, 1)),  # hybrid super-blocks
+        # DeepSeek-V3 block: the dense layer in tier 1, the expert layer in
+        # tier 2; routing drops nothing, so pooled tiers route as per client
+        ("moonlight-16b-a3b", (0, 1), (2, 3, 1)),
     ],
 )
 def test_engines_match(arch, cuts, intervals):
@@ -191,6 +194,8 @@ def _run_masked_pair(arch, cuts, intervals, seed, steps=4):
         ("smollm-135m", (1, 2), (3, 2, 1)),
         ("qwen2-1.5b", (1, 1), (2, 4, 1)),
         ("mamba2-1.3b", (1, 2), (2, 2, 1)),
+        # routed experts with no auxiliary loss: masked Engine B takes them
+        ("moonlight-16b-a3b", (0, 1), (2, 2, 1)),
     ],
 )
 def test_engines_match_masked(arch, cuts, intervals):

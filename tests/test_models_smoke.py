@@ -39,6 +39,8 @@ def test_full_spec_matches_assignment(arch):
         "phi3.5-moe-42b-a6.6b": (32, 4096, 32, 8, 6400, 32064),
         "mamba2-1.3b": (48, 2048, 0, 0, 0, 50280),
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
+        "moonlight-16b-a3b-ep8": (27, 2048, 16, 16, 11264, 163840),
     }[arch]
     got = (s.num_layers, s.d_model, s.num_heads, s.num_kv_heads, s.d_ff, s.vocab_size)
     assert got == expect, (arch, got, expect)
@@ -107,7 +109,8 @@ def test_decode_step(arch):
     assert changed
 
 
-@pytest.mark.parametrize("arch", ["smollm-135m", "qwen2-1.5b", "mamba2-1.3b", "qwen3-32b"])
+@pytest.mark.parametrize("arch", ["smollm-135m", "qwen2-1.5b", "mamba2-1.3b", "qwen3-32b",
+                                  "moonlight-16b-a3b"])
 def test_decode_matches_forward(arch):
     """Teacher-forced decode reproduces the training-path logits."""
     spec = get_reduced(arch)
@@ -154,7 +157,7 @@ def test_total_param_count_close_to_nominal():
         "smollm-135m": 135e6, "mamba2-1.3b": 1.3e9,
         "granite-moe-1b-a400m": 1.3e9, "phi3.5-moe-42b-a6.6b": 42e9,
         "jamba-1.5-large-398b": 398e9, "paligemma-3b": 2.6e9,  # LM backbone
-        "whisper-large-v3": 1.5e9,
+        "whisper-large-v3": 1.5e9, "moonlight-16b-a3b": 16e9,
     }
     for arch, nom in nominal.items():
         got = get_spec(arch).total_param_count()

@@ -7,7 +7,8 @@ program that does not fit).  Covered: the three ``tiered_aggregate``
 kernels at real leaf widths (N = 20 clients, J = 5 edges), the SWA
 attention kernels forward and backward at SmolLM's head dim, and the
 jitted VGG-16/CIFAR-10 Engine-A local-round and sync-round steps at
-20 clients.
+20 clients, and the megablox grouped products of Moonlight-16B-A3B's
+held experts at the benchmark's shapes.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -142,3 +143,30 @@ def test_vgg_engine_a_step_compiles(one_chip, fed):
     mem = _compile(step, state, batch).memory_analysis()
     # 20 f32 replicas of VGG-16's ~15 M parameters, plus the batch
     assert 1.0e9 < mem.argument_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("product", ["w1_w3", "w2", "w2_transposed", "weight_grad"])
+def test_expert_grouped_matmul_compiles(one_chip, product):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    from repro.models.layers import _megablox_args
+
+    # two clients' 2048 tokens x 6 choices, folded into 2 x 8 held-expert
+    # groups; hidden 2048, expert width 1408; bf16 operands, f32 out
+    m, d, f, G = 2 * 2048 * 6, 2048, 1408, 16
+    s = lambda shape: _sds(one_chip, shape, jnp.bfloat16)
+    sizes = _sds(one_chip, (G,), jnp.int32)
+    if product == "weight_grad":
+        pad, tiling, _ = _megablox_args(m, d, f)
+        fn = lambda x, dy, n: tgmm(x.T, dy, n, preferred_element_type=jnp.float32,
+                                   tiling=tiling)
+        args = (s((m, d)), s((m, f)))
+    else:
+        k, n_out, rhs = {"w1_w3": (d, f, (G, d, f)), "w2": (f, d, (G, f, d)),
+                         "w2_transposed": (d, f, (G, f, d))}[product]
+        pad, tiling, _ = _megablox_args(m, k, n_out)
+        fn = lambda x, w, n: gmm(x, w, n, preferred_element_type=jnp.float32,
+                                 tiling=tiling, transpose_rhs=product == "w2_transposed")
+        args = (s((m, k)), s(rhs))
+    assert pad == 0
+    assert "tpu_custom_call" in _compile(fn, *args, sizes).as_text()
