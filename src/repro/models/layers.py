@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm, tgmm as megablox_tgmm
 
 from .. import obs
@@ -523,12 +524,70 @@ def mla(params: Params, x: jax.Array, spec: ModelSpec, positions: jax.Array,
             new_cache = {"c": c, "k_pe": k_pe, "positions": k_pos, "index": idx + 1}
         kv = (c @ params["wkvb"]).reshape(B, c.shape[1], h, m.nope_dim + m.v_dim)
         k_nope, v = kv[..., : m.nope_dim], kv[..., m.nope_dim:]
-        s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
-             + jnp.einsum("bqhr,bkr->bhqk", q_pe, k_pe)).astype(jnp.float32)
-        s = s / math.sqrt(m.qk_dim) + _mask_bias(positions, k_pos, True, 0, 0)
-        w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        o = jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(B, S, h * m.v_dim)
+        with obs.scope(obs.MLA_ATTN):
+            if _takes_splash(cache, S):
+                o = mla_splash_attention(q_nope, q_pe, k_nope, k_pe, v)
+            else:
+                o = mla_dense_attention(q_nope, q_pe, k_nope, k_pe, v, positions, k_pos)
         return o @ params["wo"], new_cache
+
+
+def _takes_splash(cache: Optional[Params], S: int) -> bool:
+    """Whether ``mla``'s attention runs in the splash kernel: a whole
+    sequence (no cache) on the TPU that the kernel's blocks tile."""
+    return cache is None and jax.default_backend() == "tpu" and S % 128 == 0
+
+
+def mla_dense_attention(q_nope, q_pe, k_nope, k_pe, v, q_pos, k_pos) -> jax.Array:
+    """Causal attention of ``mla`` over every (query, key) pair in float32,
+    masked by position.  q_nope [B, S, h, nope], q_pe [B, S, h, rope],
+    k_nope [B, C, h, nope], k_pe [B, C, rope], v [B, C, h, v];
+    q_pos [S], k_pos [C].  Returns [B, S, h * v]."""
+    B, S, h, _ = q_nope.shape
+    s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+         + jnp.einsum("bqhr,bkr->bhqk", q_pe, k_pe)).astype(jnp.float32)
+    s = s / math.sqrt(q_nope.shape[-1] + q_pe.shape[-1]) + _mask_bias(q_pos, k_pos, True, 0, 0)
+    w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(B, S, h * v.shape[-1])
+
+
+def _splash_kernel(S: int, h: int, interpret: bool):
+    """The splash kernel of h causal heads over S positions (a multiple of
+    128), every block (forward, dq and dkv) of the largest of 512, 256 and
+    128 that divides S.  Built anew in each trace: its mask arrays become
+    values of the trace that builds it (splash caches the host's mask work
+    itself)."""
+    b = math.gcd(S, 512)
+    blocks = splash.BlockSizes(block_q=b, block_kv=b, block_kv_compute=b,
+                               block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+                               block_q_dq=b, block_kv_dq=b)
+    mask = splash.MultiHeadMask([splash.CausalMask((S, S))] * h)
+    return splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1, q_seq_shards=1,
+                                  interpret=interpret)
+
+
+def mla_splash_attention(q_nope, q_pe, k_nope, k_pe, v, interpret: bool = False) -> jax.Array:
+    """``mla_dense_attention`` over a whole sequence in the Pallas splash
+    kernel: it skips the (query, key) blocks the causal mask removes and
+    keeps scores and probabilities in VMEM, with float32 softmax statistics
+    and accumulators.  The heads' query is [q_nope, q_pe] scaled by
+    1/sqrt(qk) (the kernel does not scale), their key [k_nope, k_pe] with
+    the shared k_pe copied to every head (its gradient sums back over
+    them), their value v; operands rounded by ``_operand_dtype``.
+
+    The kernel masks by index: key j is seen by query i iff j <= i.  That
+    is the dense path's mask by position when positions are 0..S-1, as
+    every whole-sequence caller of ``mla`` gives them.  Shapes as
+    ``mla_dense_attention`` with C = S; S a multiple of 128."""
+    B, S, h, _ = q_nope.shape
+    dt = _operand_dtype(q_nope.dtype)
+    scale = 1.0 / math.sqrt(q_nope.shape[-1] + q_pe.shape[-1])
+    q = jnp.concatenate([q_nope, q_pe], axis=-1) * scale
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe[:, :, None], (B, S, h, k_pe.shape[-1]))], axis=-1)
+    heads = lambda a: a.swapaxes(1, 2).astype(dt)  # [B, h, S, d]
+    o = jax.vmap(_splash_kernel(S, h, interpret))(heads(q), heads(k), heads(v))
+    return o.swapaxes(1, 2).reshape(B, S, h * v.shape[-1]).astype(q_nope.dtype)
 
 
 def init_mla_cache(spec: ModelSpec, batch: int, cache_len: int) -> Params:

@@ -17,6 +17,8 @@ time is charged to the innermost name, and ``hsfl.grad`` takes every phase
 nested in its name, so it keeps its meaning):
 
 * ``hsfl.grad.mla`` — latent attention;
+* ``hsfl.grad.mla.attn`` — its scores, causal softmax and value product
+  (the splash kernel on the TPU), forward and backward;
 * ``hsfl.grad.moe.route`` — the gate, the selection, dispatch and combine;
 * ``hsfl.grad.moe.experts`` — the grouped products over the held experts;
 * ``hsfl.grad.moe.shared`` — the shared experts.
@@ -38,6 +40,7 @@ import jax
 GRAD = "hsfl.grad"
 OPT = "hsfl.opt"
 MLA = "hsfl.grad.mla"
+MLA_ATTN = "hsfl.grad.mla.attn"
 MOE_ROUTE = "hsfl.grad.moe.route"
 MOE_EXPERTS = "hsfl.grad.moe.experts"
 MOE_SHARED = "hsfl.grad.moe.shared"

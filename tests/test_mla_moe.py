@@ -2,17 +2,20 @@
 random weights at a small size: the program against the plain reference
 ``bench/reference/deepseek_v3.py``, routing with no dropped tokens, the
 expert share against the uncut layer, the registered chip share against
-its benchmark configuration, and one Engine-A round whose tiers split the
-dense layer from the expert layers."""
+its benchmark configuration, one Engine-A round whose tiers split the
+dense layer from the expert layers, and latent attention in the splash
+kernel (interpreted here) against its dense lines."""
 import dataclasses
 import json
 import sys
+from functools import partial
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
@@ -23,6 +26,7 @@ from repro.core import build_train_step_a, init_state_a  # noqa: E402
 from repro.core.tiers import default_plan  # noqa: E402
 from repro.models import layers as L  # noqa: E402
 from repro.models.model import SplittableModel  # noqa: E402
+from repro.models.spec import MlaSpec  # noqa: E402
 from repro.optim import sgd  # noqa: E402
 
 CONFIG = json.loads((ROOT / "bench/configs/moonlight16b-a3b.json").read_text())
@@ -204,3 +208,148 @@ def test_one_engine_a_round_with_cuts_across_the_dense_expert_boundary(cuts):
             np.testing.assert_allclose(np.asarray(a[:, u]), np.asarray(b[:, u]), **close)
     for a, b in zip(jax.tree.leaves(new.params["head"]), jax.tree.leaves(every["head"])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **close)
+
+
+# --------------------------------------------------------------------------- #
+# latent attention in the splash kernel against its dense lines
+# --------------------------------------------------------------------------- #
+
+
+def attn_spec():
+    """Moonlight's head dims (query/key 128 + 64, value 128) on two heads."""
+    return dataclasses.replace(small_spec(), num_heads=2, num_kv_heads=2,
+                               mla=MlaSpec(kv_rank=32, nope_dim=128, rope_dim=64, v_dim=128))
+
+
+def kernel_path(monkeypatch):
+    """Send ``mla``'s whole sequences to the splash kernel off the TPU too,
+    interpreted."""
+    monkeypatch.setattr(L, "_takes_splash", lambda cache, S: cache is None and S % 128 == 0)
+    monkeypatch.setattr(L, "mla_splash_attention",
+                        partial(L.mla_splash_attention, interpret=True))
+
+
+def client_vmap(f, cotangent):
+    """A scalar of ``f`` under a client vmap and a per-unit remat."""
+    return lambda *a: jnp.sum(jax.vmap(jax.checkpoint(f))(*a) * cotangent)
+
+
+@pytest.mark.parametrize("part", ["forward", "gradients"])
+@pytest.mark.parametrize("S", [256, 384])
+def test_the_splash_kernel_matches_the_dense_attention(S, part):
+    # 2 clients x 1 sequence; float32 operands off the TPU
+    N, h = 2, 2
+    shapes = [(N, 1, S, h, 128), (N, 1, S, h, 64), (N, 1, S, h, 128), (N, 1, S, 64),
+              (N, 1, S, h, 128)]  # q_nope, q_pe, k_nope, k_pe, v
+    args = [jax.random.normal(k, s) for k, s in zip(jax.random.split(jax.random.PRNGKey(S), 5),
+                                                     shapes)]
+    pos = jnp.arange(S)
+    dense = lambda *a: L.mla_dense_attention(*a, pos, pos)
+    kernel = lambda *a: L.mla_splash_attention(*a, interpret=True)
+    if part == "forward":
+        want, got = jax.vmap(dense)(*args), jax.vmap(kernel)(*args)
+        assert got.shape == (N, 1, S, h * 128)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=1e-5)
+        return
+    ct = jax.random.normal(jax.random.PRNGKey(7), (N, 1, S, h * 128))
+    want = jax.grad(client_vmap(dense, ct), range(5))(*args)
+    got = jax.grad(client_vmap(kernel, ct), range(5))(*args)
+    for name, a, b in zip(["q_nope", "q_pe", "k_nope", "k_pe", "v"], got, want):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a) / scale, np.asarray(b) / scale,
+                                   rtol=0, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("S", [256, 384])
+def test_every_mla_weight_gets_the_dense_gradient_through_the_kernel(S, monkeypatch):
+    spec = attn_spec()
+    p = L.init_mla(jax.random.PRNGKey(1), spec)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 1, S, spec.d_model))
+    ct = jax.random.normal(jax.random.PRNGKey(3), x.shape)
+    pos = jnp.arange(S)
+
+    def grads():
+        f = lambda p, x: L.mla(p, x, spec, pos)[0]
+        return jax.grad(client_vmap(f, ct), (0, 1))(
+            jax.tree.map(lambda w: jnp.broadcast_to(w, (2,) + w.shape), p), x)
+
+    want = grads()
+    with monkeypatch.context() as m:
+        kernel_path(m)
+        got = grads()
+    flat_want, flat_got = named(want[0]), named(got[0])
+    flat_want["x"], flat_got["x"] = want[1], got[1]
+    for k in flat_want:
+        scale = float(jnp.max(jnp.abs(flat_want[k]))) or 1.0
+        np.testing.assert_allclose(np.asarray(flat_got[k]) / scale,
+                                   np.asarray(flat_want[k]) / scale,
+                                   rtol=0, atol=1e-5, err_msg=k)
+
+
+def test_a_kernel_built_in_one_program_serves_the_next(monkeypatch):
+    # the kernel's mask arrays are values of the trace that builds the
+    # kernel: built inside a checkpoint under one jit, then a second
+    # program (a remat'ed layer, then a scan over two layers) runs too
+    kernel_path(monkeypatch)
+    spec, S = attn_spec(), 128
+    p = L.init_mla(jax.random.PRNGKey(1), spec)
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, S, spec.d_model))
+    layer = jax.checkpoint(lambda p, x: L.mla(p, x, spec, jnp.arange(S))[0])
+    first = jax.jit(jax.grad(lambda p, x: jnp.sum(layer(p, x))))
+
+    def second(p, x):
+        x, _ = lax.scan(lambda x, _: (layer(p, x), None), layer(p, x), None, length=2)
+        return jnp.sum(x)
+
+    with jax.checking_leaks():  # no trace's value outlives it
+        g1 = first(p, x)
+        loss, g2 = jax.jit(jax.value_and_grad(second))(p, x)
+    for leaf in jax.tree.leaves((g1, loss, g2)):
+        assert bool(jnp.all(jnp.isfinite(leaf)))
+
+
+def test_the_training_path_hands_mla_positions_0_to_s(monkeypatch):
+    # the kernel masks by index, the dense lines by position: the two agree
+    # because every whole-sequence caller passes positions 0..S-1
+    spec = small_spec()
+    model = SplittableModel(spec)
+    seen, mla = [], L.mla
+
+    def spy(params, x, spec, positions, cache=None):
+        jax.debug.callback(lambda p: seen.append(np.asarray(p)), positions)
+        return mla(params, x, spec, positions, cache)
+
+    monkeypatch.setattr(L, "mla", spy)
+    params = model.init_params(jax.random.PRNGKey(0))
+    batch = {k: v.reshape(2, 1, -1) for k, v in tokens(spec, B=2, S=24).items()}
+    jax.block_until_ready(jax.jit(jax.vmap(jax.value_and_grad(model.loss_fn),
+                                           in_axes=(None, 0)))(params, batch))
+    assert len(seen) >= spec.first_dense + spec.n_units
+    for p in seen:
+        np.testing.assert_array_equal(p, np.arange(24))
+
+
+def test_a_cache_or_an_untiled_length_keeps_the_dense_lines(monkeypatch):
+    spec = attn_spec()
+    p = L.init_mla(jax.random.PRNGKey(1), spec)
+    called = []
+
+    def kernel(q_nope, q_pe, k_nope, k_pe, v):
+        called.append(q_nope.shape[1])
+        return jnp.zeros(q_nope.shape[:2] + (q_nope.shape[2] * v.shape[-1],))
+
+    monkeypatch.setattr(L, "mla_splash_attention", kernel)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def run(S, cache=None):
+        x = jnp.zeros((1, S, spec.d_model))
+        jax.eval_shape(lambda x: L.mla(p, x, spec, jnp.arange(S), cache), x)
+
+    run(200)  # not a multiple of 128
+    run(1, L.init_mla_cache(spec, 1, 256))  # decode
+    assert called == []
+    run(256)  # the kernel's case on the TPU
+    assert called == [256]
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    run(256)  # off the TPU
+    assert called == [256]

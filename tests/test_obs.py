@@ -3,9 +3,12 @@
 The phase scopes must be metadata only: the Engine-A round compiled with
 them and with every scope replaced by a null context is the same optimized
 HLO once op metadata is stripped.  A scope that restructured the program
-(a loop split per tier, a barrier, a checkpoint) fails here.
+(a loop split per tier, a barrier, a checkpoint) fails here.  Latent
+attention's kernel, whose backward pass runs inside the kernel's own
+``custom_vjp``, carries its phase in every op, forward and backward.
 """
 import contextlib
+import functools
 import re
 import time
 
@@ -20,6 +23,7 @@ from repro.configs.shapes import concrete_inputs
 from repro.core import build_train_step_a, init_state_a
 from repro.core.tiers import default_plan
 from repro.data import FederatedLoader
+from repro.models import layers as L
 from repro.models.model import SplittableModel
 from repro.models.vgg import build_model
 from repro.optim import sgd
@@ -102,6 +106,31 @@ def test_scopes_leave_the_compiled_round_unchanged(make, round_, monkeypatch,
     assert "hsfl." not in plain
     assert "metadata=" not in _without_metadata(named)
     assert _without_metadata(named) == _without_metadata(plain)
+
+
+def test_the_splash_kernel_runs_inside_the_attention_phase(monkeypatch):
+    # one mla call of two clients on the kernel path (interpreted off the
+    # TPU), forward and backward: the forward, dq and dkv kernels' every op
+    monkeypatch.setattr(L, "_takes_splash", lambda cache, S: cache is None)
+    monkeypatch.setattr(L, "mla_splash_attention",
+                        functools.partial(L.mla_splash_attention, interpret=True))
+    spec = get_reduced("moonlight-16b-a3b-ep8")
+    p = L.init_mla(jax.random.PRNGKey(0), spec)
+    x = jnp.zeros((N, 1, 128, spec.d_model))
+
+    def loss(p, x):
+        with obs.scope(obs.GRAD):
+            f = lambda x: L.mla(p, x, spec, jnp.arange(128))[0]
+            return jnp.sum(jax.vmap(jax.checkpoint(f))(x))
+
+    hlo = jax.jit(jax.grad(loss)).lower(p, x).compile().as_text()
+    names = [n for n in re.findall(r'op_name="([^"]*)"', hlo) if "splash_mha_" in n]
+    kernels = {re.search(r"splash_mha_(fwd|dq|dkv)", n).group(1) for n in names}
+    assert kernels == {"fwd", "dq", "dkv"}
+    # the innermost phase of each op's path is the one it is charged to
+    innermost = {re.findall(r"hsfl\.[\w.]+", n)[-1] for n in names}
+    assert innermost == {obs.MLA_ATTN}
+    assert all(obs.MLA in n.split(obs.MLA_ATTN)[0] for n in names)
 
 
 def test_sync_level_names():

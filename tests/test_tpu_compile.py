@@ -7,8 +7,9 @@ program that does not fit).  Covered: the three ``tiered_aggregate``
 kernels at real leaf widths (N = 20 clients, J = 5 edges), the SWA
 attention kernels forward and backward at SmolLM's head dim, and the
 jitted VGG-16/CIFAR-10 Engine-A local-round and sync-round steps at
-20 clients, and the megablox grouped products of Moonlight-16B-A3B's
-held experts at the benchmark's shapes.
+20 clients, the megablox grouped products of Moonlight-16B-A3B's
+held experts at the benchmark's shapes, and its latent attention in the
+splash kernel, forward and backward.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -170,3 +171,21 @@ def test_expert_grouped_matmul_compiles(one_chip, product):
         args = (s((m, k)), s(rhs))
     assert pad == 0
     assert "tpu_custom_call" in _compile(fn, *args, sizes).as_text()
+
+
+def test_mla_splash_attention_compiles(one_chip):
+    """Moonlight-16B-A3B's latent attention in the splash kernel at the
+    benchmark's shapes (2 clients x 1 x 2048 tokens, 16 heads, query/key
+    128 + 64, value 128, bf16 operands), forward and backward under
+    ``jax.checkpoint`` and a client ``vmap``.  Temporaries: 102.0 MB (the
+    heads' queries, keys and values laid out for the kernel, and their
+    gradients), where one float32 score tensor of the dense path is 537 MB."""
+    from repro.models.layers import mla_splash_attention
+
+    N, B, S, h = 2, 1, 2048, 16
+    s = lambda *shape: _sds(one_chip, (N, B, S) + shape, jnp.bfloat16)
+    attn = jax.checkpoint(mla_splash_attention)
+    fn = jax.grad(lambda *a: jnp.sum(jax.vmap(attn)(*a).astype(jnp.float32)), range(5))
+    c = _compile(fn, s(h, 128), s(h, 64), s(h, 128), s(64), s(h, 128))
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 128e6
