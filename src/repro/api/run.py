@@ -19,6 +19,7 @@ resolved spec, so the artifact alone reproduces the run.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
@@ -249,8 +250,12 @@ def _participation_masks(built: BuiltExperiment, cuts) -> Optional[np.ndarray]:
     ).masks
 
 
-def _make_step(built: BuiltExperiment, model, plan, opt, with_mask: bool):
-    """Jitted engine step for one tier plan (re-built on control switches)."""
+def _make_step(
+    built: BuiltExperiment, model, plan, opt, with_mask: bool, mesh=None,
+    client_axes=("data",),
+):
+    """Jitted engine step for one tier plan (re-built on control switches),
+    sharded over ``mesh`` when one is given (Engine A only)."""
     import jax
 
     from ..core.engine import build_train_step_a, build_train_step_b
@@ -261,6 +266,7 @@ def _make_step(built: BuiltExperiment, model, plan, opt, with_mask: bool):
     )
     if built.spec.run.engine == "a":
         builder = build_train_step_a
+        kwargs.update(mesh=mesh, client_axes=client_axes)
         if (
             built.guard is not None
             and built.faults is not None
@@ -316,7 +322,6 @@ def _train(built: BuiltExperiment, cuts, intervals) -> Dict[str, Any]:
 
     masks = _participation_masks(built, cuts)
     with_mask = masks is not None or inject
-    init = init_state_a if rc.engine == "a" else init_state_b
 
     # sharded / async execution (DESIGN.md §17) — capability-checked at
     # build time (engine A, no privacy/classes/faults/control)
@@ -326,17 +331,16 @@ def _train(built: BuiltExperiment, cuts, intervals) -> Dict[str, Any]:
     use_async = any(s_eff)
     mesh, client_axes = None, ("data",)
     if rc.sharding is not None:
-        from ..core.sharded import init_sharded_state_a
         from ..launch.mesh import make_debug_mesh
 
         sh = rc.sharding
         mesh = make_debug_mesh(data=sh.data, model=sh.model, pods=sh.pods)
         client_axes = ("pod", "data") if sh.pods else ("data",)
-        state = init_sharded_state_a(
-            model, plan, opt, key, mesh, client_axes=client_axes
-        )
-    else:
-        state = init(model, plan, opt, key)
+    init = (
+        partial(init_state_a, mesh=mesh, client_axes=client_axes)
+        if rc.engine == "a" else init_state_b
+    )
+    state = init(model, plan, opt, key)
 
     trainer, step = None, None
     if use_async:
@@ -352,15 +356,10 @@ def _train(built: BuiltExperiment, cuts, intervals) -> Dict[str, Any]:
             compressor=built.compressor, with_mask=with_mask,
             guard=guard_kw, mesh=mesh, client_axes=client_axes,
         )
-    elif mesh is not None:
-        from ..core.sharded import build_sharded_train_step_a
-
-        step = build_sharded_train_step_a(
-            model, plan, opt, mesh, client_axes=client_axes,
-            compressor=built.compressor, with_mask=with_mask,
-        )
     else:
-        step = _make_step(built, model, plan, opt, with_mask)
+        step = _make_step(
+            built, model, plan, opt, with_mask, mesh, client_axes
+        )
 
     members = None
     if inject:

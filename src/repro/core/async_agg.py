@@ -42,8 +42,8 @@ from .tiers import (
     TierPlan,
     _group_mean,
     _group_mean_masked,
-    combine_tiers,
-    tier_subtrees,
+    _put_tier,
+    _tier_part,
 )
 
 Params = Dict[str, Any]
@@ -120,11 +120,8 @@ def fed_level_apply(
         raise ValueError(
             f"tier {m} is the top tier — its sync is never deferred"
         )
-    parts = tier_subtrees(params, plan)
-    src = (
-        parts[m] if snapshot is None
-        else tier_subtrees(snapshot, plan)[m]
-    )
+    part = _tier_part(params, plan, m)
+    src = part if snapshot is None else _tier_part(snapshot, plan, m)
     groups, _interval = plan.levels(m)[-1]
     fed = compress_fn is not None and plan.entities[m] > 1
     original = src
@@ -135,10 +132,9 @@ def fed_level_apply(
         agg = _group_mean(p, groups)
     if snapshot is not None:
         agg = jax.tree.map(
-            lambda a, now, snap: a + (now - snap), agg, parts[m], src
+            lambda a, now, snap: a + (now - snap), agg, part, src
         )
-    parts[m] = agg
-    return combine_tiers(parts, params)
+    return _put_tier(params, agg, plan, m)
 
 
 @dataclass
@@ -158,9 +154,9 @@ class AsyncTrainer:
     One instance owns the per-round ``fed_round`` step dispatch (the
     production specialization — at most 2^(#gated tiers) compiled
     variants), the pending-sync queue, and the jitted per-tier
-    ``fed_level_apply`` programs.  Works over single-host state or the
-    sharded state of ``core.sharded`` (pass ``step_builder`` /
-    ``jit_apply`` accordingly — ``make_async_trainer`` wires both).
+    ``fed_level_apply`` programs.  Works over single-host state or state
+    sharded over a mesh (``make_async_trainer`` builds the step either
+    way; the jitted applies take the state's sharding as it comes).
 
     Per round r::
 
@@ -291,23 +287,15 @@ def make_async_trainer(
     mesh=None,
     client_axes=("data",),
 ) -> AsyncTrainer:
-    """AsyncTrainer over the single-host engine, or the sharded engine
-    when ``mesh`` is given (``core.sharded``)."""
-    if mesh is None:
-        def builder(fed):
-            return jax.jit(build_train_step_a(
-                model, plan, opt, fed_round=fed, compressor=compressor,
-                with_mask=with_mask, guard=guard, with_sync_weights=True,
-            ))
-    else:
-        from .sharded import build_sharded_train_step_a
+    """AsyncTrainer over the Engine-A step, sharded over ``mesh`` when
+    one is given."""
+    def builder(fed):
+        return jax.jit(build_train_step_a(
+            model, plan, opt, fed_round=fed, compressor=compressor,
+            with_mask=with_mask, guard=guard, with_sync_weights=True,
+            mesh=mesh, client_axes=client_axes,
+        ))
 
-        def builder(fed):
-            return build_sharded_train_step_a(
-                model, plan, opt, mesh, client_axes=client_axes,
-                fed_round=fed, compressor=compressor, with_mask=with_mask,
-                guard=guard, with_sync_weights=True,
-            )
     return AsyncTrainer(
         plan, builder, staleness=staleness, compressor=compressor,
         with_mask=with_mask, guard=guard,

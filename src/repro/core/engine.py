@@ -1,9 +1,11 @@
 """HSFL execution engines.
 
 Engine A ("sync-groups", production): every tier's parameters are stacked
-per-client on axis 0 and sharded over the `data` (and `pod`) mesh axes. The
-hierarchy is realized purely as the multi-timescale aggregation schedule of
-``tiers.synchronize`` — memory-balanced and collective-efficient on TPU.
+per-client on axis 0. The hierarchy is realized purely as the
+multi-timescale aggregation schedule of ``tiers.synchronize`` —
+memory-balanced and collective-efficient on TPU. One step serves one chip
+and a mesh: given a mesh, the same step runs under ``shard_map`` with the
+client axis sharded over the ``data`` (and ``pod``) axes (DESIGN.md §17).
 
 Engine B ("split-placement", reference): tier-1 params stacked per client,
 tier-2 per entity, tier-3 single — the literal SFL dataflow where activations
@@ -15,13 +17,14 @@ replicas + Eq. 3 entity sync + Eq. 4 fed-server aggregation at I_m).
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
 from ..optim import Optimizer
@@ -75,10 +78,36 @@ def unreplicate(params: Params) -> Params:
 # --------------------------------------------------------------------------- #
 
 
-def init_state_a(model, plan: TierPlan, opt: Optimizer, key) -> TrainState:
+def _client_shards(plan: TierPlan, mesh, client_axes) -> int:
+    """Shards of the client axis over ``client_axes``; N must divide."""
+    D = math.prod(mesh.shape[a] for a in client_axes)
+    if plan.num_clients % D != 0:
+        raise ValueError(
+            f"num_clients={plan.num_clients} must divide over the {D} "
+            f"client shards of mesh axes {tuple(client_axes)!r}"
+        )
+    return D
+
+
+def init_state_a(
+    model, plan: TierPlan, opt: Optimizer, key, mesh=None,
+    client_axes=("data",),
+) -> TrainState:
+    """Client-stacked initial state.  With a ``mesh`` the same state is
+    placed with its client axis sharded over ``client_axes``
+    (``launch.sharding.train_pspecs``): same key, same replicas."""
     p0 = model.init_params(key)
     params = replicate_for_clients(p0, plan.num_clients)
-    return TrainState(params=params, opt_state=opt.init(params), step=jnp.zeros((), jnp.int32))
+    state = TrainState(params=params, opt_state=opt.init(params), step=jnp.zeros((), jnp.int32))
+    if mesh is None:
+        return state
+    from ..launch.sharding import train_pspecs
+
+    _client_shards(plan, mesh, client_axes)
+    specs = train_pspecs(state, tuple(client_axes), plan.num_clients)
+    return jax.tree.map(
+        lambda x, ps: jax.device_put(x, NamedSharding(mesh, ps)), state, specs
+    )
 
 
 def _masked_select(new, old, w: jax.Array):
@@ -96,12 +125,16 @@ def _masked_select(new, old, w: jax.Array):
     return jax.tree.map(f, new, old)
 
 
-def masked_mean_loss(losses: jax.Array, w: jax.Array) -> jax.Array:
+def masked_mean_loss(
+    losses: jax.Array, w: jax.Array, client_axes: Tuple[str, ...] = ()
+) -> jax.Array:
     """Participation-weighted round loss Σ w_i·loss_i / Σ w_i (0.0 for a
-    zero-participant round — the round is a no-op, DESIGN.md §12)."""
-    total = jnp.sum(w)
+    zero-participant round — the round is a no-op, DESIGN.md §12).
+    Under ``shard_map`` both sums run over the ``client_axes`` too."""
+    fleet = (lambda x: lax.psum(x, client_axes)) if client_axes else (lambda x: x)
+    total = fleet(jnp.sum(w))
     return jnp.where(
-        total > 0.0, jnp.sum(losses * w) / jnp.maximum(total, 1.0), 0.0
+        total > 0.0, fleet(jnp.sum(losses * w)) / jnp.maximum(total, 1.0), 0.0
     )
 
 
@@ -109,7 +142,7 @@ def build_train_step_a(
     model, plan: TierPlan, opt: Optimizer, *, sync_opt_state: bool = False,
     fed_round=None, compressor=None, with_mask: bool = False,
     class_members=None, privacy=None, guard: Optional[GuardSpec] = None,
-    with_sync_weights: bool = False,
+    with_sync_weights: bool = False, mesh=None, client_axes=("data",),
 ) -> Callable[..., Tuple[TrainState, jax.Array]]:
     """Engine-A step: vmapped per-client update + hierarchical aggregation.
 
@@ -178,7 +211,35 @@ def build_train_step_a(
     these at snapshot time so a deferred fed-server apply weights clients
     identically to the in-step levels; re-deriving health at apply time
     would quarantine a different set.
+
+    ``mesh`` runs the same step under ``jax.shard_map`` (DESIGN.md §17):
+    the client axis of the state, the batch, the mask and the sync
+    weights is sharded over ``client_axes`` (``launch.sharding``'s
+    ``train_pspecs`` / ``batch_pspecs``; the state comes from
+    ``init_state_a(..., mesh=mesh)``), the round loss is ``psum``-reduced
+    and replicated, and ``tiers.synchronize`` lowers each level that
+    spans shards to an einsum + ``psum``.  The caller jits the returned
+    step either way.  ``privacy`` and ``class_members`` have no mesh
+    lowering.
     """
+    N = plan.num_clients  # the clients one program holds: all, or a shard's
+    ca = ()
+    if mesh is not None:
+        if privacy is not None:
+            raise ValueError(
+                "unsupported spec combination: sharding × privacy requires "
+                "privacy=None — DP noise keys fold (seed, leaf, step), so "
+                "the draw cannot be reproduced bit-exactly across shard "
+                "layouts (DESIGN.md §15/§17)"
+            )
+        if class_members is not None:
+            raise ValueError(
+                "unsupported spec combination: sharding × classes requires "
+                "class_members=None — the ragged per-class sync has no "
+                "sharded collective lowering yet (DESIGN.md §14/§17)"
+            )
+        ca = tuple(client_axes)
+        N //= _client_shards(plan, mesh, ca)
     compress_fn = (
         None if compressor is None
         else lambda x: jax.vmap(lambda v: compressor.transform(v))(x)
@@ -205,8 +266,13 @@ def build_train_step_a(
             )
         return synchronize(
             tree, plan, step, fed_round=fed_round, compress_fn=compress,
-            mask=mask, guard=g,
+            mask=mask, guard=g, client_axes=ca,
         )
+
+    def _mean_loss(losses):
+        if ca:
+            return lax.psum(jnp.sum(losses), ca) / plan.num_clients
+        return jnp.mean(losses)
 
     def _step(state: TrainState, batch: Params, mask) -> Tuple[TrainState, jax.Array]:
         with obs.scope(obs.GRAD):
@@ -224,7 +290,7 @@ def build_train_step_a(
             # the health-weighted mean over finite losses only — every
             # arithmetic op here sees sanitized values, so a healthy round
             # runs clean under JAX_DEBUG_NANS.
-            health, _ = guard_health(new_params, plan.num_clients, guard)
+            health, _ = guard_health(new_params, N, guard, ca)
             lfin = jnp.isfinite(losses)
             health = health * lfin.astype(jnp.float32)
             w = (
@@ -234,23 +300,25 @@ def build_train_step_a(
             new_params = _masked_select(new_params, state.params, w)
             new_opt = _masked_select(new_opt, state.opt_state, w)
             lsafe = jnp.where(lfin, losses, 0.0)
-            loss = masked_mean_loss(lsafe, w)
+            loss = masked_mean_loss(lsafe, w, ca)
             if mask is None:
                 # all-healthy unmasked rounds must report the exact plain
                 # mean (bit-for-bit zero-fault collapse); lsafe == losses
                 # there, so this stays NaN-free under JAX_DEBUG_NANS
-                loss = jnp.where(
-                    jnp.all(w >= 1.0), jnp.mean(lsafe), loss
+                all_healthy = (
+                    lax.psum(jnp.sum(w >= 1.0), ca) >= plan.num_clients
+                    if ca else jnp.all(w >= 1.0)
                 )
+                loss = jnp.where(all_healthy, _mean_loss(lsafe), loss)
             sync_mask = w
         elif mask is None:
-            loss = jnp.mean(losses)
+            loss = _mean_loss(losses)
             sync_mask = None
         else:
             w = mask.astype(jnp.float32)
             new_params = _masked_select(new_params, state.params, w)
             new_opt = _masked_select(new_opt, state.opt_state, w)
-            loss = masked_mean_loss(losses, w)
+            loss = masked_mean_loss(losses, w, ca)
             sync_mask = mask
         new_params = _sync(
             new_params, state.step, compress=_fed_wire(state.step),
@@ -277,15 +345,29 @@ def build_train_step_a(
         new_state = TrainState(new_params, new_opt, state.step + 1)
         if with_sync_weights:
             ww = (
-                jnp.ones((plan.num_clients,), jnp.float32)
+                jnp.ones((N,), jnp.float32)
                 if sync_mask is None else sync_mask.astype(jnp.float32)
             )
             return new_state, loss, ww
         return new_state, loss
 
-    if with_mask:
-        return _step
-    return lambda state, batch: _step(state, batch, None)
+    step = _step if with_mask else (lambda state, batch: _step(state, batch, None))
+    if mesh is None:
+        return step
+    from ..launch.sharding import batch_pspecs, train_pspecs
+
+    rows = P(ca if len(ca) > 1 else ca[0])
+
+    def sharded_step(state: TrainState, batch: Params, *mask):
+        state_specs = train_pspecs(state, ca, plan.num_clients)
+        out_specs = (state_specs, P()) + ((rows,) if with_sync_weights else ())
+        return jax.shard_map(
+            step, mesh=mesh,
+            in_specs=(state_specs, batch_pspecs(batch, ca)) + (rows,) * len(mask),
+            out_specs=out_specs, check_vma=False,
+        )(state, batch, *mask)
+
+    return sharded_step
 
 
 # --------------------------------------------------------------------------- #

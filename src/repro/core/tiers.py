@@ -15,6 +15,7 @@ Synchronization operates on client-stacked parameter pytrees (axis 0 = client).
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -45,8 +46,6 @@ class GuardSpec:
     norm_factor: float = 1e4
 
     def __post_init__(self):
-        import math
-
         if self.norm_factor <= 1.0 or not math.isfinite(self.norm_factor):
             raise ValueError(
                 f"norm_factor must be finite and > 1: {self.norm_factor}"
@@ -54,7 +53,8 @@ class GuardSpec:
 
 
 def guard_health(
-    tree: Params, num_clients: int, guard: GuardSpec
+    tree: Params, num_clients: int, guard: GuardSpec,
+    client_axes: Tuple[str, ...] = (),
 ) -> Tuple[jax.Array, Params]:
     """(health mask [N] float32, sanitized tree) for a client-stacked pytree.
 
@@ -64,6 +64,11 @@ def guard_health(
     tree is bit-identical to the input (the ``JAX_DEBUG_NANS`` contract
     pinned in ``tests/test_faults.py``).  Leaves without a leading client
     axis (scalar bookkeeping) pass through unchecked.
+
+    Inside ``shard_map`` over the mesh's ``client_axes``, ``tree`` is this
+    shard's ``num_clients`` rows: the finite and norm² checks stay local,
+    and the fleet median is taken over an ``all_gather`` of the norm²
+    row — the same multiset of values, so the same median.
     """
     N = num_clients
     stacked = [
@@ -88,7 +93,10 @@ def guard_health(
         if hasattr(x, "ndim") and x.ndim > 0 and x.shape[0] == N:
             f = x.reshape(N, -1).astype(jnp.float32)
             norm2 = norm2 + jnp.sum(f * f, axis=1)
-    med = jnp.median(norm2)
+    med = jnp.median(
+        lax.all_gather(norm2, client_axes, axis=0, tiled=True)
+        if client_axes else norm2
+    )
     blowup = norm2 > guard.norm_factor * jnp.maximum(med, jnp.float32(1e-30))
     health = (finite & ~blowup).astype(jnp.float32)
     return health, clean
@@ -285,11 +293,9 @@ def _put_tier(params: Params, part: Params, plan: TierPlan, m: int) -> Params:
 def _group_mean(tree: Params, groups: int) -> Params:
     """Mean over client groups, broadcast back. Leaves: [N, ...].
 
-    ``core.sharded`` lowers this same level semantics onto a device mesh
-    (DESIGN.md §17): when the group boundaries align with the shard
-    boundaries the per-shard computation IS this function (bit-identical);
-    otherwise the mean becomes a matmul-shaped one-hot einsum + ``psum``,
-    equal up to f32 cross-device reduction order.
+    On a mesh (DESIGN.md §17) a level whose groups each lie on one shard
+    runs this function on the shard's rows, bit-identical to one host;
+    a level whose groups span shards runs ``_matmul_group_mean`` instead.
     """
 
     def f(x):
@@ -330,9 +336,9 @@ def _group_mean_masked(
     pre-compression params here, otherwise a silent group "keeps" a
     lossy-coded copy it never uploaded (DESIGN.md §9/§12).
 
-    The sharded engine (``core.sharded``) reproduces these weights with
+    ``_matmul_group_mean`` reproduces these weights across shards with
     per-shard partial sums + ``lax.psum``; a zero-participant group's
-    keep-fallback becomes a ``where`` against the gathered mask.  Note the
+    keep-fallback becomes a ``where`` against the psum'd counts.  Note the
     group mean is NOT idempotent on already-averaged rows when weights
     differ, which is why the deferred fed-server replay in
     ``core.async_agg.fed_level_apply`` re-derives the level from a
@@ -359,6 +365,66 @@ def _group_mean_masked(
     return jax.tree.map(f, tree, keep)
 
 
+def _client_base(axis_names: Tuple[str, ...], n_local: int) -> jax.Array:
+    """Global client id of this shard's slot 0.
+
+    Clients lay out row-major over the client axes (the order
+    ``jax.device_put`` shards axis 0), so the shard index is the mixed-
+    radix expansion of the axis indices in the given order.
+    """
+    idx = jnp.zeros((), jnp.int32)
+    for ax in axis_names:
+        idx = idx * lax.psum(1, ax) + lax.axis_index(ax)
+    return idx * n_local
+
+
+def _matmul_group_mean(
+    tree: Params,
+    groups: int,
+    n_global: int,
+    axis_names: Tuple[str, ...],
+    w: Optional[jax.Array],
+    keep: Optional[Params] = None,
+) -> Params:
+    """Cross-device group mean as one matmul-shaped pass per leaf.
+
+    ``tree`` leaves are local shards [N_local, ...]; every group of the
+    ``n_global``-client fleet spans shards.  The fed-server batch
+    (groups=1) is the degenerate case: one [1, N_local] × [N_local, D]
+    contraction per leaf, psum'd over the client axes.  Equal to
+    ``_group_mean`` / ``_group_mean_masked`` up to f32 reduction order:
+    each shard sums its N_local rows, then the D partials are summed.
+    """
+    leaves = jax.tree.leaves(tree)
+    n_local = leaves[0].shape[0]
+    base = _client_base(axis_names, n_local)
+    gs = n_global // groups
+    gid = (base + jnp.arange(n_local, dtype=jnp.int32)) // gs  # [N_local]
+    onehot = (
+        gid[:, None] == jnp.arange(groups, dtype=jnp.int32)[None, :]
+    ).astype(jnp.float32)                                      # [N_local, G]
+    wl = jnp.ones((n_local,), jnp.float32) if w is None else w.astype(jnp.float32)
+    ww = onehot * wl[:, None]                                  # [N_local, G]
+    cnt = lax.psum(jnp.sum(ww, axis=0), axis_names)            # [G]
+    if keep is None:
+        keep = tree
+
+    def f(x, k):
+        flat = x.reshape(n_local, -1).astype(jnp.float32)
+        # HIGHEST: a TPU's default f32 matmul rounds its inputs to bf16,
+        # which would quantize every parameter in the mean
+        partial_sums = jnp.einsum(
+            "ng,nd->gd", ww, flat, precision=lax.Precision.HIGHEST
+        )                                                      # [G, D] matmul
+        tot = lax.psum(partial_sums, axis_names)
+        mean = tot / jnp.maximum(cnt, 1.0)[:, None]
+        mine = mean[gid].astype(x.dtype).reshape(x.shape)      # gather my group
+        alive = (cnt[gid] > 0.0).reshape((n_local,) + (1,) * (x.ndim - 1))
+        return jnp.where(alive, mine, k)
+
+    return jax.tree.map(f, tree, keep)
+
+
 def synchronize(
     params: Params,
     plan: TierPlan,
@@ -368,6 +434,7 @@ def synchronize(
     compress_fn=None,
     mask=None,
     guard: Optional[GuardSpec] = None,
+    client_axes: Tuple[str, ...] = (),
 ) -> Params:
     """Apply the per-tier aggregation schedule at round ``step`` (post-update).
 
@@ -419,16 +486,23 @@ def synchronize(
     sanitized tree is bit-identical to the input and the health mask is
     all-ones, so the result collapses bit-for-bit onto the unguarded path.
 
-    Two other call sites reuse these exact level semantics (DESIGN.md §17):
-    ``core.sharded.build_sharded_train_step_a`` lowers every level onto a
-    device mesh under ``shard_map`` (same schedule, same mask/compression/
-    guard gating, cross-device means via ``lax`` collectives), and
+    ``client_axes`` names the mesh axes the client axis is sharded over
+    when this runs inside ``shard_map`` (DESIGN.md §17); ``params`` and
+    ``mask`` are then this shard's rows.  Per level, groups that each lie
+    on one shard (``groups`` divisible by the shard count) take the
+    one-host mean on the shard's ``groups // shards`` groups; groups that
+    span shards take ``_matmul_group_mean``'s einsum + ``psum``.  Empty
+    (one host) is the plain path.
+
     ``core.async_agg.fed_level_apply`` replays a single tier's deferred
     fed-server level from a snapshot — deliberately NOT by re-invoking
     ``synchronize``, because the group mean is not bit-idempotent.
     """
+    shards = math.prod(lax.axis_size(a) for a in client_axes)
     if guard is not None:
-        health, params = guard_health(params, plan.num_clients, guard)
+        health, params = guard_health(
+            params, plan.num_clients // shards, guard, client_axes
+        )
         mask = health if mask is None else mask.astype(jnp.float32) * health
     if fed_round is not None and not isinstance(fed_round, (tuple, list)):
         fed_round = (bool(fed_round),) * plan.M
@@ -458,9 +532,16 @@ def synchronize(
                 original = p
                 if fed:
                     p = jax.tree.map(compress_fn, p)
+                if groups % shards:
+                    return _matmul_group_mean(
+                        p, groups, plan.num_clients, client_axes, mask,
+                        keep=original,
+                    )
                 if mask is not None:
-                    return _group_mean_masked(p, groups, mask, keep=original)
-                return _group_mean(p, groups)
+                    return _group_mean_masked(
+                        p, groups // shards, mask, keep=original
+                    )
+                return _group_mean(p, groups // shards)
 
             with obs.scope(obs.sync_level(m, li, len(levels))):
                 if li == run[0]:

@@ -106,8 +106,8 @@ def train_pspecs(
     client_axes: Tuple[str, ...],
     num_clients: Optional[int] = None,
 ) -> Any:
-    """Client-axis-only pspecs for the sharded *training* step
-    (``core.sharded``): shard axis 0 of every client-stacked leaf over
+    """Client-axis-only pspecs for the Engine-A *training* step on a
+    mesh: shard axis 0 of every client-stacked leaf over
     the client mesh axes, replicate everything else (scalar bookkeeping
     like adam's step counter).
 

@@ -55,7 +55,7 @@ def train(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--shard-data", type=int, default=0, metavar="D",
                     help="shard the client-stacked axis over D devices "
-                         "(core.sharded shard_map engine; needs XLA_FLAGS="
+                         "(the Engine-A step under shard_map; needs XLA_FLAGS="
                          "'--xla_force_host_platform_device_count=D' on CPU)")
     ap.add_argument("--shard-pods", type=int, default=0, metavar="P",
                     help="additionally shard clients over P pods "
@@ -142,9 +142,9 @@ def train(argv=None) -> dict:
         The async trainer generalizes exactly this dispatch — with all-zero
         staleness it picks the same specialized variants; with s_m > 0 the
         due tier's fed level is snapshotted and folded back s_m rounds
-        later (core.async_agg).  It also hosts the sharded step builder.
+        later (core.async_agg).
         """
-        if mesh is not None or staleness:
+        if staleness:
             from ..core.async_agg import make_async_trainer
 
             trainer = make_async_trainer(
@@ -158,31 +158,20 @@ def train(argv=None) -> dict:
         def dispatch(state_, batch_, r):
             fed = fed_round(plan_.intervals, r)
             if fed not in cache:
-                cache[fed] = jax.jit(
-                    build_train_step_a(model, plan_, opt, fed_round=fed)
-                )
+                cache[fed] = jax.jit(build_train_step_a(
+                    model, plan_, opt, fed_round=fed, mesh=mesh,
+                    client_axes=client_axes,
+                ))
             return cache[fed](state_, batch_)
 
         return dispatch, None
 
     def make_probe_step(plan_):
-        if mesh is not None:
-            from ..core.sharded import build_sharded_train_step_a
-
-            return build_sharded_train_step_a(
-                model, plan_, opt, mesh, client_axes=client_axes
-            )
-        return jax.jit(build_train_step_a(model, plan_, opt))
+        return jax.jit(build_train_step_a(
+            model, plan_, opt, mesh=mesh, client_axes=client_axes))
 
     key = jax.random.PRNGKey(args.seed)
-    if mesh is not None:
-        from ..core.sharded import init_sharded_state_a
-
-        state = init_sharded_state_a(
-            model, plan, opt, key, mesh, client_axes=client_axes
-        )
-    else:
-        state = init_state_a(model, plan, opt, key)
+    state = init_state_a(model, plan, opt, key, mesh=mesh, client_axes=client_axes)
     step = make_probe_step(plan)
 
     if args.auto_optimize:

@@ -90,3 +90,18 @@ def test_message_shape_is_uniform():
     msg = str(e.value)
     assert msg.startswith("unsupported spec combination: ")
     assert " requires " in msg and " — " in msg
+
+
+@pytest.mark.parametrize("feature", ["privacy", "class_members"])
+def test_the_mesh_step_refuses_what_the_matrix_refuses(feature):
+    # the engine's backstop for direct callers, in the matrix's words
+    from repro.core import build_train_step_a
+    from repro.core.tiers import default_plan
+    from repro.launch.mesh import make_debug_mesh
+    from repro.optim import sgd
+
+    combo = "privacy" if feature == "privacy" else "classes"
+    with pytest.raises(ValueError, match=f"{MSG}: sharding × {combo} requires"):
+        build_train_step_a(None, default_plan(4, 4), sgd(0.1),
+                           mesh=make_debug_mesh(data=1, model=1),
+                           **{feature: object()})

@@ -108,6 +108,22 @@ def test_scopes_leave_the_compiled_round_unchanged(make, round_, monkeypatch,
     assert _without_metadata(named) == _without_metadata(plain)
 
 
+def test_the_mesh_round_carries_every_phase():
+    # the same step under shard_map names the same phases as on one host
+    from repro.launch.mesh import make_debug_mesh
+
+    model, plan, batch = _lm()
+    opt = sgd(1e-2)
+    state = jax.eval_shape(lambda: init_state_a(model, plan, opt, jax.random.PRNGKey(0)))
+    step = build_train_step_a(model, plan, opt, fed_round=ROUNDS["full_fed"],
+                              mesh=make_debug_mesh(data=1, model=1))
+    text = jax.jit(step).lower(state, batch).as_text(debug_info=True)
+    assert "/shard_map" in text
+    for phase in (obs.GRAD, obs.OPT, "hsfl.sync.t1.fed", "hsfl.sync.t2.entity",
+                  "hsfl.sync.t2.fed", "hsfl.sync.t3.entity", "hsfl.sync.t3.fed"):
+        assert phase in text, phase
+
+
 def test_the_splash_kernel_runs_inside_the_attention_phase(monkeypatch):
     # one mla call of two clients on the kernel path (interpreted off the
     # TPU), forward and backward: the forward, dq and dkv kernels' every op

@@ -132,9 +132,6 @@ ENGINE_SCRIPT = textwrap.dedent("""
     from repro.configs.shapes import concrete_inputs
     from repro.core.async_agg import make_async_trainer
     from repro.core.engine import build_train_step_a, init_state_a
-    from repro.core.sharded import (
-        build_sharded_train_step_a, init_sharded_state_a,
-    )
     from repro.core.tiers import GuardSpec, default_plan
     from repro.launch.mesh import make_debug_mesh
     from repro.models.model import SplittableModel
@@ -166,15 +163,10 @@ ENGINE_SCRIPT = textwrap.dedent("""
 
     def run(sharded, **kw):
         with_mask = kw.get("with_mask", False)
-        if sharded:
-            state = init_sharded_state_a(model, plan, opt,
-                                         jax.random.PRNGKey(0), mesh)
-            mk = lambda f: build_sharded_train_step_a(
-                model, plan, opt, mesh, fed_round=f, **kw)
-        else:
-            state = init_state_a(model, plan, opt, jax.random.PRNGKey(0))
-            mk = lambda f: jax.jit(build_train_step_a(
-                model, plan, opt, fed_round=f, **kw))
+        m = mesh if sharded else None
+        state = init_state_a(model, plan, opt, jax.random.PRNGKey(0), mesh=m)
+        mk = lambda f: jax.jit(build_train_step_a(
+            model, plan, opt, fed_round=f, mesh=m, **kw))
         steps, losses = {}, []
         for r in range(R):
             f = fed(r)
@@ -214,8 +206,7 @@ ENGINE_SCRIPT = textwrap.dedent("""
     # sync dispatch (the same shard_map programs run in the same order)
     _, sync_params = run(True)
     tr = make_async_trainer(model, plan, opt, staleness=0, mesh=mesh)
-    state = init_sharded_state_a(model, plan, opt, jax.random.PRNGKey(0),
-                                 mesh)
+    state = init_state_a(model, plan, opt, jax.random.PRNGKey(0), mesh=mesh)
     for r in range(R):
         state, _ = tr.run_round(state, batches[r], r)
     assert not tr.pending
@@ -226,34 +217,61 @@ ENGINE_SCRIPT = textwrap.dedent("""
     # s=1: defer, then drain right at the due round — equivalent to the
     # in-step fed levels up to cross-device reduction order
     tr1 = make_async_trainer(model, plan, opt, staleness=1, mesh=mesh)
-    state = init_sharded_state_a(model, plan, opt, jax.random.PRNGKey(0),
-                                 mesh)
+    state = init_state_a(model, plan, opt, jax.random.PRNGKey(0), mesh=mesh)
     for r in range(2):
         state, loss = tr1.run_round(state, batches[r], r)
         assert np.isfinite(float(loss))
     assert {p.tier for p in tr1.pending} == {0, 1}
     state = tr1.drain(state)
     # reference: the sharded sync engine over the same 2 rounds
-    st = init_sharded_state_a(model, plan, opt, jax.random.PRNGKey(0), mesh)
+    st = init_state_a(model, plan, opt, jax.random.PRNGKey(0), mesh=mesh)
     steps = {}
     for r in range(2):
         f = fed(r)
         if f not in steps:
-            steps[f] = build_sharded_train_step_a(
-                model, plan, opt, mesh, fed_round=f)
+            steps[f] = jax.jit(build_train_step_a(
+                model, plan, opt, fed_round=f, mesh=mesh))
         st, _ = steps[f](st, batches[r])
     for a, b in zip(jax.tree.leaves(state.params),
                     jax.tree.leaves(st.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-6)
+
+    # api.run's mesh branch: the same spec sharded over data=4 and on
+    # one device (tier 1's 2 edges span shards: the einsum + psum level)
+    from repro.api import (
+        ExperimentSpec, ModelCfg, RunCfg, SolverCfg, SystemCfg, run as api_run,
+    )
+    from repro.api.spec import ShardingCfg
+
+    def api_losses(sharding):
+        spec = ExperimentSpec(
+            model=ModelCfg(arch="smollm-135m", variant="reduced", batch=2,
+                           seq=8),
+            system=SystemCfg(preset="paper-three-tier", num_clients=N,
+                             num_edges=2, seed=0),
+            solver=SolverCfg(kind="fixed", cuts=(1, 2), intervals=(2, 2, 1)),
+            run=RunCfg(mode="train", seed=0, rounds=R, lr=1e-2,
+                       dataset_size=32, sharding=sharding),
+        )
+        return api_run(spec).train
+
+    sh_run, ref_run = api_losses(ShardingCfg(data=4)), api_losses(None)
+    assert sh_run["sharding"]["client_shards"] == 4
+    assert "sharding" not in ref_run
+    np.testing.assert_allclose(
+        sh_run["losses"], ref_run["losses"], rtol=2e-5,
+        err_msg="api.run: sharded losses diverge",
+    )
     print("SHARDED-ENGINE-OK")
 """)
 
 
 @pytest.mark.slow
 def test_sharded_engine_a_equivalence():
-    """core.sharded == core.engine across mask x compression x guard, plus
-    the async trainer's staleness-0 bit-exact collapse on the mesh."""
+    """The Engine-A step on a 4-device mesh == on one device across mask x
+    compression x guard, the async trainer's staleness-0 bit-exact collapse
+    on the mesh, and api.run's mesh branch against its unsharded run."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", ENGINE_SCRIPT], env=env,

@@ -100,7 +100,7 @@ def test_cache_pspecs_decode_vs_long():
 
 
 def test_train_pspecs_client_axis_only():
-    """The sharded training step (core.sharded) shards ONLY the client
+    """The Engine-A step on a mesh shards ONLY the client
     axis: no leaf may pick up a ``model`` entry (the param_pspecs(tp=1)
     pitfall its docstring documents)."""
     p = abstract_params("smollm-135m", client=8)

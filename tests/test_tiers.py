@@ -373,14 +373,14 @@ def test_in_place_sync_leaves_an_unsynced_tier_untouched():
 RESULT = re.compile(r"->\s*tensor<([0-9x]+)x[a-z0-9]+>")
 
 
-def _lowered_round(make, fed):
+def _lowered_round(make, fed, mesh=None):
     from repro.core import build_train_step_a, init_state_a
     from repro.optim import sgd
 
     model, plan, batch = make()
     opt = sgd(1e-2)
     state = jax.eval_shape(lambda: init_state_a(model, plan, opt, jax.random.PRNGKey(0)))
-    step = build_train_step_a(model, plan, opt, fed_round=fed)
+    step = build_train_step_a(model, plan, opt, fed_round=fed, mesh=mesh)
     return jax.jit(step).lower(state, batch).as_text(), state, plan
 
 
@@ -425,8 +425,13 @@ def _results(text, op, shapes):
 
 
 @pytest.mark.parametrize("round_", ["local", "fed_FTT", "fed_TTT"])
-def test_lm_round_rebuilds_no_unit_stack_by_concatenate(round_):
-    text, state, plan = _lowered_round(_lm_round_parts, ROUNDS[round_])
+@pytest.mark.parametrize("on_mesh", [False, True], ids=["host", "mesh"])
+def test_lm_round_rebuilds_no_unit_stack_by_concatenate(on_mesh, round_):
+    # the mesh case is the same step under shard_map on a one-device mesh
+    from repro.launch.mesh import make_debug_mesh
+
+    mesh = make_debug_mesh(data=1, model=1) if on_mesh else None
+    text, state, plan = _lowered_round(_lm_round_parts, ROUNDS[round_], mesh)
     stacks = {tuple(x.shape) for x in jax.tree.leaves(state.params["units"])}
     assert all(s[:2] == (plan.num_clients, plan.n_units) for s in stacks)
     assert _results(text, "concatenate", stacks) == []
